@@ -17,7 +17,9 @@ its perf point and ``python -m repro.obs report --kernels`` /
 On this CPU container Pallas runs in interpret mode, so the Pallas rows'
 absolute wall-clock is mechanism-true but not TPU-predictive (rows carry
 ``interpret: true``); the jnp-path rows (baseline, dynamic-k) are real
-XLA:CPU timings, and the roofline columns are analytic either way.
+XLA:CPU timings. The roofline columns are analytic, at the peaks of the
+local chip (``repro.obs.costmodel.PEAKS``); off those chips they are left
+out.
 """
 from __future__ import annotations
 
@@ -29,18 +31,19 @@ def run(serving: bool = True, reps: int = 3, warmup: int = 1):
     from repro.obs import costmodel as CM
     from repro.obs import profile as P
 
+    hw = CM.local_hardware()        # None off the chips in CM.PEAKS
     rows = P.profile_kernels(
         gemm_shapes=((128, 128, 128), (128, 256, 128)),
         ks=(8, 24),
         formats=((4, 8, -6), (8, 15, -14)),
         flash_shapes=((2, 256, 2, 2, 64),),
-        reps=reps, warmup=warmup)
+        reps=reps, warmup=warmup, hw=hw)
 
     entry = {
         "kind": "kernel_bench",
         "backend": jax.default_backend(),
         "interpret": jax.default_backend() != "tpu",
-        "hardware": CM.TPU_POD_CHIP.name,
+        "hardware": hw and hw.name,
         "rows": [{k: v for k, v in r.items() if k != "samples"}
                  for r in rows],
     }
@@ -67,14 +70,17 @@ def run(serving: bool = True, reps: int = 3, warmup: int = 1):
     obs.append_bench("kernels", entry)
 
     # harness contract: (name, us_per_call, derived) rows for run.py's CSV;
-    # derived = fraction of the analytic roofline achieved
+    # derived = fraction of the analytic roofline achieved ("" where the
+    # device has no peaks to draw a roofline from)
     out = []
     for r in rows:
         fmt = (f"_k{r['k']}" if r.get("k") is not None else "")
         blk = ("_b" + "x".join(map(str, r["block"]))
                if r.get("block") else "")
         out.append((f"{r['kernel']}_{r['shape']}{fmt}{blk}",
-                    r["median_s"] * 1e6, round(r["roofline_frac"], 6)))
+                    r["median_s"] * 1e6,
+                    round(r["roofline_frac"], 6) if "roofline_frac" in r
+                    else ""))
     if serving_profile:
         pre = serving_profile["prefill"]
         pct = serving_profile["decode"]["percentiles"]

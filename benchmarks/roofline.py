@@ -43,7 +43,9 @@ from repro.configs import SHAPES
 
 # hardware peaks live in repro.obs.costmodel (single source: the measured
 # cost model and these analytic terms must price the same machine)
-from repro.obs.costmodel import TPU_POD_CHIP as _HW
+from repro.obs.costmodel import hardware_for
+
+_HW = hardware_for("TPU v5 lite")     # the v5e pod this model prices
 
 PEAK = _HW.peak_flops
 HBM = _HW.hbm_bytes_per_s

@@ -236,14 +236,19 @@ class JOps(Backend):
 
     def shift(self, a, c): return a + jnp.asarray(c, a.dtype)
 
+    # HIGHEST: on a TPU an f32 contraction at default precision runs in
+    # bf16 passes; the f32 serving path must compute the f32 it states
+    # (a no-op for bf16 operands and on the CPU)
     def matmul(self, a, b):
-        return jnp.matmul(a, b, preferred_element_type=self.accum_dtype).astype(
+        return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST,
+                          preferred_element_type=self.accum_dtype).astype(
             self.compute_dtype
         )
 
     def einsum(self, subscripts, a, b):
         return jnp.einsum(
-            subscripts, a, b, preferred_element_type=self.accum_dtype
+            subscripts, a, b, precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=self.accum_dtype
         ).astype(self.compute_dtype)
 
     def tanh(self, a): return jnp.tanh(a)

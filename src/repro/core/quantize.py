@@ -96,6 +96,12 @@ def _quantize_impl(x: jax.Array, fmt_name: str) -> jax.Array:
     return y
 
 
+def _clip_i32(v, lo: int, hi: int):
+    # int32 bounds: Python-int bounds become i64 under x64, and Mosaic
+    # cannot lower the i64 -> i32 convert that jnp.clip then inserts
+    return jnp.clip(v, jnp.int32(lo), jnp.int32(hi))
+
+
 def quantize_to_k(x: jax.Array, k) -> jax.Array:
     """Mantissa-only RNE rounding to k bits where ``k`` may be a *traced*
     scalar (jnp int), not just a Python int.
@@ -117,7 +123,7 @@ def quantize_to_k(x: jax.Array, k) -> jax.Array:
         raise TypeError(f"carrier must be f32/f64, got {dt}")
     k = jnp.asarray(k, jnp.int32)
     s = total_mant - (k - 1)               # bits to drop; <= 0 → identity
-    eff = jnp.clip(s, 1, total_mant).astype(uint_t)
+    eff = _clip_i32(s, 1, total_mant).astype(uint_t)
     one = jnp.asarray(1, uint_t)
     bits = jax.lax.bitcast_convert_type(x, uint_t)
     half = (one << (eff - one)) - one      # 2^{s-1} - 1
@@ -142,9 +148,9 @@ def pow2(e, dt) -> jax.Array:
     else:
         raise TypeError(f"carrier must be f32/f64, got {dt}")
     normal = e >= 1 - bias
-    bits_n = jnp.clip(e + bias, 0, 2 * bias).astype(uint_t) << mant
+    bits_n = _clip_i32(e + bias, 0, 2 * bias).astype(uint_t) << mant
     bits_s = (jnp.asarray(1, uint_t)
-              << jnp.clip(e - min_e, 0, mant).astype(uint_t))
+              << _clip_i32(e - min_e, 0, mant).astype(uint_t))
     return jax.lax.bitcast_convert_type(jnp.where(normal, bits_n, bits_s), dt)
 
 

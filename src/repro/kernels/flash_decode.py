@@ -35,11 +35,21 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG = -1e30
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
-def _flash_decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
+def _flash_decode_kernel(fmt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
                          m_ref, l_ref, acc_ref, *, n_s_steps: int,
-                         block_s: int, scale: float):
+                         block_s: int, scale: float, quantize: bool,
+                         has_subnormals: bool, saturating: bool):
+    if quantize:
+        from repro.core.quantize import quantize_to_format
+        from repro.kernels.quant_matmul import smem_format
+        kk, emax, emin = smem_format(fmt_ref)
+        qf = lambda t: quantize_to_format(t, kk, emax, emin,
+                                          has_subnormals, saturating)
+    else:
+        qf = lambda t: t
     s_idx = pl.program_id(2)
 
     @pl.when(s_idx == 0)
@@ -48,14 +58,15 @@ def _flash_decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0]                       # [G, D]
-    k = k_ref[0, :, 0, :]                 # [bs, D]
-    v = v_ref[0, :, 0, :]                 # [bs, D]
-    length = len_ref[0]
+    q = qf(q_ref[0, 0].astype(jnp.float32))       # [G, D]
+    k = qf(k_ref[0].astype(jnp.float32))          # [bs, D]
+    v = qf(v_ref[0].astype(jnp.float32))          # [bs, D]
+    length = len_ref[pl.program_id(0)]
 
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale  # [G, bs]
+    s = jnp.dot(q, k.T, precision=HIGHEST,
+                preferred_element_type=jnp.float32) * scale
     pos = s_idx * block_s + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s = jnp.where(pos < length, s, NEG)
+    s = jnp.where(pos < length, s, jnp.float32(NEG))   # no f64 under x64
 
     m_prev = m_ref[...]                   # [G, 1]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -63,85 +74,7 @@ def _flash_decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
     p = jnp.exp(s - m_new)                # [G, bs]
     l_new = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
     acc_new = alpha * acc_ref[...] + jnp.dot(
-        p, v.astype(jnp.float32), preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
-    l_ref[...] = l_new
-    acc_ref[...] = acc_new
-
-    @pl.when(s_idx == n_s_steps - 1)
-    def _done():
-        o_ref[0, 0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
-
-
-def flash_decode_attention(q, k, v, lengths, *, block_s: int = 256,
-                           interpret: bool = False) -> jax.Array:
-    """q: [B, K, G, D] (grouped query heads); k, v: [B, S, K, D];
-    lengths: [B] valid cache lengths. Returns [B, K, G, D]."""
-    B, K, G, D = q.shape
-    S = k.shape[1]
-    bs = min(block_s, S)
-    assert S % bs == 0
-    n_s = S // bs
-    scale = D ** -0.5
-    kernel = functools.partial(_flash_decode_kernel, n_s_steps=n_s,
-                               block_s=bs, scale=scale)
-    return pl.pallas_call(
-        kernel,
-        grid=(B, K, n_s),
-        in_specs=[
-            pl.BlockSpec((1,), lambda b, h, s: (b,)),
-            pl.BlockSpec((1, 1, G, D), lambda b, h, s: (b, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, D), lambda b, h, s: (b, s, h, 0)),
-            pl.BlockSpec((1, bs, 1, D), lambda b, h, s: (b, s, h, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, s: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, K, G, D), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, D), jnp.float32),
-        ],
-        interpret=interpret,
-    )(lengths, q, k, v)
-
-
-# --------------------------------------------------------------------------
-# certificate-aware decode: per-layer (k, emax, emin) via scalar prefetch
-# --------------------------------------------------------------------------
-
-def _flash_decode_fmt_kernel(fmt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                             m_ref, l_ref, acc_ref, *, n_s_steps: int,
-                             block_s: int, scale: float,
-                             has_subnormals: bool, saturating: bool):
-    from repro.core.quantize import quantize_to_format
-
-    kk, emax, emin = fmt_ref[0], fmt_ref[1], fmt_ref[2]
-    qf = lambda t: quantize_to_format(t, kk, emax, emin,
-                                      has_subnormals, saturating)
-    s_idx = pl.program_id(2)
-
-    @pl.when(s_idx == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    q = qf(q_ref[0, 0].astype(jnp.float32))          # [G, D]
-    k = qf(k_ref[0, :, 0, :].astype(jnp.float32))    # [bs, D]
-    v = qf(v_ref[0, :, 0, :].astype(jnp.float32))    # [bs, D]
-    length = len_ref[pl.program_id(0)]
-
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-    pos = s_idx * block_s + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s = jnp.where(pos < length, s, NEG)
-
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)
-    l_new = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
-    acc_new = alpha * acc_ref[...] + jnp.dot(
-        p, v, preferred_element_type=jnp.float32)
+        p, v, precision=HIGHEST, preferred_element_type=jnp.float32)
     m_ref[...] = m_new
     l_ref[...] = l_new
     acc_ref[...] = acc_new
@@ -150,6 +83,61 @@ def _flash_decode_fmt_kernel(fmt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     def _done():
         o_ref[0, 0] = qf(acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
+
+def _flash_decode(q, k, v, lengths, fmt, *, quantize: bool,
+                  has_subnormals: bool, saturating: bool, block_s: int,
+                  interpret: bool) -> jax.Array:
+    B, K, G, D = q.shape
+    S = k.shape[1]
+    bs = min(block_s, S)
+    assert S % bs == 0
+    n_s = S // bs
+    kernel = functools.partial(_flash_decode_kernel, n_s_steps=n_s,
+                               block_s=bs, scale=D ** -0.5,
+                               quantize=quantize,
+                               has_subnormals=has_subnormals,
+                               saturating=saturating)
+    # the cache is read as [B, S, K*D] (a free row-major reshape): head h
+    # is lane block h of width D, so each KV block is a (bs, D) tile that
+    # meets the TPU's (8, 128) tiling, where a (1, D) slice of [.., K, D]
+    # would not
+    kv_spec = pl.BlockSpec((1, bs, D), lambda b, h, s, fmt, ln: (b, s, h))
+    # i32 zeros: a literal 0 would be an i64 block index under x64
+    q_spec = pl.BlockSpec((1, 1, G, D), lambda b, h, s, fmt, ln: (
+        b, h, jnp.int32(0), jnp.int32(0)))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B, K, n_s),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
+        scratch_shapes=[
+            pltpu.VMEM((G, 1), jnp.float32),
+            pltpu.VMEM((G, 1), jnp.float32),
+            pltpu.VMEM((G, D), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, K, G, D), q.dtype),
+        interpret=interpret,
+    )(jnp.asarray(fmt, jnp.int32), jnp.asarray(lengths, jnp.int32), q,
+      k.reshape(B, S, K * D), v.reshape(B, S, K * D))
+
+
+def flash_decode_attention(q, k, v, lengths, *, block_s: int = 256,
+                           interpret: bool = False) -> jax.Array:
+    """q: [B, K, G, D] (grouped query heads); k, v: [B, S, K, D];
+    lengths: [B] valid cache lengths. Returns [B, K, G, D]."""
+    return _flash_decode(q, k, v, lengths, jnp.zeros((3,), jnp.int32),
+                         quantize=False, has_subnormals=True,
+                         saturating=True, block_s=block_s,
+                         interpret=interpret)
+
+
+# --------------------------------------------------------------------------
+# certificate-aware decode: per-layer (k, emax, emin) via scalar prefetch
+# --------------------------------------------------------------------------
 
 def flash_decode_certified(q, k, v, lengths, fmt, *,
                            has_subnormals: bool = True,
@@ -168,37 +156,10 @@ def flash_decode_certified(q, k, v, lengths, fmt, *,
     (block_s ≥ S) the result is bitwise
     :func:`flash_decode_quantized_ref`.
     """
-    B, K, G, D = q.shape
-    S = k.shape[1]
-    bs = min(block_s, S)
-    assert S % bs == 0
-    n_s = S // bs
-    scale = D ** -0.5
-    kernel = functools.partial(_flash_decode_fmt_kernel, n_s_steps=n_s,
-                               block_s=bs, scale=scale,
-                               has_subnormals=has_subnormals,
-                               saturating=saturating)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, K, n_s),
-        in_specs=[
-            pl.BlockSpec((1, 1, G, D), lambda b, h, s, fmt, ln: (b, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, D), lambda b, h, s, fmt, ln: (b, s, h, 0)),
-            pl.BlockSpec((1, bs, 1, D), lambda b, h, s, fmt, ln: (b, s, h, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, s, fmt, ln: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, D), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, K, G, D), q.dtype),
-        interpret=interpret,
-    )(jnp.asarray(fmt, jnp.int32), jnp.asarray(lengths, jnp.int32), q, k, v)
+    return _flash_decode(q, k, v, lengths, fmt, quantize=True,
+                         has_subnormals=has_subnormals,
+                         saturating=saturating, block_s=block_s,
+                         interpret=interpret)
 
 
 def flash_decode_quantized_ref(q, k, v, lengths, fmt, *,
@@ -221,14 +182,16 @@ def flash_decode_quantized_ref(q, k, v, lengths, fmt, *,
     qq, kq, vq = qf(q), qf(k), qf(v)
 
     def one(qb, kb, vb, ln):      # [G,D], [S,D], [S,D], scalar length
-        s = jnp.dot(qb, kb.T, preferred_element_type=jnp.float32) * scale
+        s = jnp.dot(qb, kb.T, precision=HIGHEST,
+                    preferred_element_type=jnp.float32) * scale
         pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(pos < ln, s, NEG)
         m = jnp.maximum(jnp.full_like(s[:, :1], NEG),
                         jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m)
         l = jnp.sum(p, axis=1, keepdims=True)
-        acc = jnp.dot(p, vb, preferred_element_type=jnp.float32)
+        acc = jnp.dot(p, vb, precision=HIGHEST,
+                      preferred_element_type=jnp.float32)
         return qf(acc / l)
 
     out = jax.vmap(jax.vmap(one, in_axes=(0, 1, 1, None)),

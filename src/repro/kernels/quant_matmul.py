@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def _rne_to_k_bits(x, k: int):
     if k >= 24:
@@ -90,7 +92,8 @@ def quant_matmul_dynamic_k(x: jax.Array, w: jax.Array, k) -> jax.Array:
 
     xq = quantize_to_k(jnp.asarray(x, jnp.float32), k)
     wq = quantize_to_k(jnp.asarray(w, jnp.float32), k)
-    out = jnp.matmul(xq, wq, preferred_element_type=jnp.float32)
+    out = jnp.matmul(xq, wq, precision=HIGHEST,
+                     preferred_element_type=jnp.float32)
     return quantize_to_k(out, k)
 
 
@@ -105,8 +108,10 @@ def quant_matmul_format_ref(x: jax.Array, w: jax.Array, fmt,
     so one jit compilation serves every certified format (the serving
     backend's per-scope maps and the scanned per-layer arrays both rely on
     it); the subnormal/saturation flags are static (a v3 serving map is
-    flag-uniform by construction). This is the function the scalar-prefetch
-    Pallas kernel below must match bitwise.
+    flag-uniform by construction). The contraction is summed in
+    :func:`format_block_k` blocks, in order, exactly as the Pallas kernel
+    below walks its K grid axis — the function that kernel must match
+    bitwise.
     """
     from repro.core.quantize import quantize_to_format
 
@@ -114,10 +119,29 @@ def quant_matmul_format_ref(x: jax.Array, w: jax.Array, fmt,
     k, emax, emin = fmt[0], fmt[1], fmt[2]
     q = lambda v: quantize_to_format(v, k, emax, emin,
                                      has_subnormals, saturating)
-    out = jnp.matmul(q(jnp.asarray(x, jnp.float32)),
-                     q(jnp.asarray(w, jnp.float32)),
-                     preferred_element_type=jnp.float32)
-    return q(out)
+    xq = q(jnp.asarray(x, jnp.float32))
+    wq = q(jnp.asarray(w, jnp.float32))
+    K, N = wq.shape
+    bk = format_block_k(K)
+    xb = jnp.moveaxis(xq.reshape(*xq.shape[:-1], K // bk, bk), -2, 0)
+    wb = wq.reshape(K // bk, bk, N)
+
+    def add_block(acc, blk):
+        xk, wk = blk
+        return acc + jnp.matmul(xk, wk, precision=HIGHEST,
+                                preferred_element_type=jnp.float32), None
+
+    acc, _ = jax.lax.scan(add_block,
+                          jnp.zeros(xq.shape[:-1] + (N,), jnp.float32),
+                          (xb, wb))
+    return q(acc)
+
+
+def smem_format(fmt_ref):
+    """The (k, emax, emin) triple of a scalar-prefetched i32[3] ref, each
+    broadcast to an i32[1, 1] vector: Mosaic bitcasts only vectors, and
+    the format's power-of-two constants are built by bitcasting."""
+    return tuple(jnp.full((1, 1), fmt_ref[i], jnp.int32) for i in range(3))
 
 
 def _quant_matmul_format_kernel(fmt_ref, x_ref, w_ref, o_ref, acc, *,
@@ -125,7 +149,7 @@ def _quant_matmul_format_kernel(fmt_ref, x_ref, w_ref, o_ref, acc, *,
                                 saturating: bool):
     from repro.core.quantize import quantize_to_format
 
-    k, emax, emin = fmt_ref[0], fmt_ref[1], fmt_ref[2]
+    k, emax, emin = smem_format(fmt_ref)
     q = lambda v: quantize_to_format(v, k, emax, emin,
                                      has_subnormals, saturating)
 
@@ -135,6 +159,7 @@ def _quant_matmul_format_kernel(fmt_ref, x_ref, w_ref, o_ref, acc, *,
 
     acc[...] += jnp.dot(q(x_ref[...].astype(jnp.float32)),
                         q(w_ref[...].astype(jnp.float32)),
+                        precision=HIGHEST,
                         preferred_element_type=jnp.float32)
 
     @pl.when(pl.program_id(2) == n_k_steps - 1)
@@ -154,9 +179,10 @@ def quant_matmul_format(x: jax.Array, w: jax.Array, fmt, *,
     serving format (or serving a per-scope v3 map) costs zero recompiles,
     vs one full Mosaic compile per format for the static-``k`` kernel
     above (benchmarks/analysis_speed.py measures the difference). Rounding
-    semantics are exactly :func:`quant_matmul_format_ref`'s; with a single
-    K step (block_k ≥ K) the two are bitwise identical — the acceptance
-    test for v3 certificates serves through both and compares bits.
+    semantics are exactly :func:`quant_matmul_format_ref`'s; with
+    ``block_k = format_block_k(K)`` the two also sum the contraction in
+    the same blocks and order — the acceptance test for v3 certificates
+    serves through both and compares bits.
     """
     M, K = x.shape
     K2, N = w.shape
@@ -195,6 +221,13 @@ def _pick_block(dim: int, target: int) -> int:
     return dim
 
 
+def format_block_k(K: int) -> int:
+    """Contraction block of the format GEMM, shared by the kernel and its
+    eager mirror. 512 keeps an f32 weight tile at 512 KiB (a whole
+    18944-deep down-projection column block would be 19 MB of VMEM)."""
+    return _pick_block(K, 512)
+
+
 def quant_matmul_format_dispatch(x: jax.Array, w: jax.Array, fmt,
                                  has_subnormals: bool = True,
                                  saturating: bool = True, *,
@@ -204,12 +237,11 @@ def quant_matmul_format_dispatch(x: jax.Array, w: jax.Array, fmt,
     Pallas kernel on TPU, :func:`quant_matmul_format_ref` elsewhere.
 
     Batched ``x`` ([..., K]) is flattened to [M, K] for the kernel and
-    restored after. The kernel always runs with a SINGLE K step
-    (block_k = K) so its accumulation order — and therefore its bits —
-    match the eager reference exactly; the differential test serves the
-    same GEMM through both paths and compares bits. ``force_kernel``
-    overrides the platform check (tests exercise the kernel in interpret
-    mode on CPU)."""
+    restored after. The kernel steps through K in
+    :func:`format_block_k` blocks, the order the eager reference sums
+    in; the differential test serves the same GEMM through both paths
+    and compares bits. ``force_kernel`` overrides the platform check
+    (tests exercise the kernel in interpret mode on CPU)."""
     use_kernel = force_kernel
     if use_kernel is None:
         use_kernel = jax.default_backend() == "tpu"
@@ -227,7 +259,7 @@ def quant_matmul_format_dispatch(x: jax.Array, w: jax.Array, fmt,
         jnp.asarray(x, jnp.float32).reshape(M, K), jnp.asarray(w, jnp.float32),
         fmt, has_subnormals=has_subnormals, saturating=saturating,
         block_m=_pick_block(M, 256), block_n=_pick_block(N, 256),
-        block_k=K, interpret=interpret)
+        block_k=format_block_k(K), interpret=interpret)
     return out.reshape(*lead, N)
 
 

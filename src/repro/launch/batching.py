@@ -23,11 +23,14 @@ that requests join and leave independently:
 Bit-for-bit contract: a request's tokens are identical to running that
 request ALONE through the single-device eager reference
 (:func:`reference_generate`: ``UnrolledLayerLoop``-composed backend, batch
-1, unpadded prefill, no mesh). This holds because every per-lane row of
-the transformer is bitwise independent of batch composition — f32 matmul
-rows don't see other rows, masked-softmax columns beyond a lane's length
-contribute exact zeros, cache writes are vmapped per lane — which the
-engine tests assert against staggered-arrival schedules.
+1, the same page-padded prefill shape, no mesh). This holds because every
+per-lane row of the transformer is bitwise independent of batch
+composition — f32 matmul rows don't see other rows, masked-softmax columns
+beyond a lane's length contribute exact zeros, cache writes are vmapped
+per lane — which the engine tests assert against staggered-arrival
+schedules. Prefill is compared at like shapes: a GEMM's rows are not
+bitwise independent of its row COUNT (XLA:CPU blocks a 6-row and a 16-row
+product differently), so the reference pads exactly as the engine does.
 
 Mesh execution: with a (data, model) mesh from
 :func:`repro.launch.mesh.make_serving_mesh`, params shard column-parallel
@@ -51,6 +54,7 @@ import numpy as np
 from repro import configs, obs
 from repro.core.backend import JOps, UnrolledLayerLoop
 from repro.launch import mesh as meshlib
+from repro.launch.jitcache import use_compile_cache
 from repro.launch import serve
 from repro.models import transformer as T
 from repro.parallel import sharding as sh
@@ -73,6 +77,7 @@ class _Lane:
     pages: int                  # pages reserved from the pool
     out: List[int] = dataclasses.field(default_factory=list)
     t_admit: float = 0.0
+    logits: List[np.ndarray] = dataclasses.field(default_factory=list)
 
 
 def make_backend(sc: serve.ServeConfig, *, mesh=None, unrolled: bool = False):
@@ -108,13 +113,17 @@ class ContinuousBatchingEngine:
     bitwise-safe column-parallel serving sharding. ``registry`` (a
     :class:`repro.obs.MetricsRegistry`) receives occupancy / queue-depth
     gauges and per-lane ``serve.decode_latency_s{lane=N}`` histograms.
+    With ``keep_logits`` every response also carries ``"logits"``, an f32
+    ``[n_tokens, vocab]`` host array: row t is the distribution token t
+    was taken from (row 0 from the prefill, the rest from decode steps) —
+    what a correctness check compares against a reference.
     """
 
     def __init__(self, arch_cfg, sc: serve.ServeConfig, params, *,
                  mesh=None, n_lanes: int = 4, max_seq: int = 64,
                  page_size: int = 16, queue_depth: int = 8,
                  total_pages: Optional[int] = None, eos_id: int = -1,
-                 registry=None, certset=None):
+                 registry=None, certset=None, keep_logits: bool = False):
         if max_seq % page_size:
             raise ValueError(f"max_seq {max_seq} must be a whole number of "
                              f"pages (page_size {page_size})")
@@ -128,6 +137,7 @@ class ContinuousBatchingEngine:
         self.eos_id = eos_id
         self.registry = registry
         self.certset = certset
+        self.keep_logits = keep_logits
         self.mesh = mesh
         self.bk = make_backend(sc, mesh=mesh)
 
@@ -156,6 +166,9 @@ class ContinuousBatchingEngine:
 
     def _build_steps(self):
         cfg, bk, S = self.arch_cfg, self.bk, self.max_seq
+        # logits leave the device only when asked for: on a mesh they are
+        # gathered from vocab shards
+        keep = self.keep_logits
 
         def prefill_fn(params, tokens, length):
             # batch-1 prefill into a fresh cache; bitwise == the same rows
@@ -166,9 +179,10 @@ class ContinuousBatchingEngine:
             cache = T.init_cache(cfg, 1, S, jnp.float32, per_lane_idx=True)
             logits, cache = T.forward(bk, params, cfg, tokens, cache=cache,
                                       q_offset=jnp.zeros((1,), jnp.int32))
-            tok = jnp.argmax(logits[0, length - 1, :], axis=-1)
+            row = logits[0, length - 1, :]
+            tok = jnp.argmax(row, axis=-1)
             cache = {**cache, "idx": jnp.full_like(cache["idx"], length)}
-            return tok.astype(jnp.int32), cache
+            return tok.astype(jnp.int32), row if keep else None, cache
 
         def insert_fn(cache, sl, lane):
             def one(b, s):
@@ -185,8 +199,9 @@ class ContinuousBatchingEngine:
             cache = {**cache, "idx": idx.astype(cache["idx"].dtype)}
             logits, cache = T.forward(bk, params, cfg, tokens[:, None],
                                       cache=cache, q_offset=offsets)
-            nxt = jnp.argmax(logits[:, -1, :], axis=-1)
-            return nxt.astype(jnp.int32), cache
+            rows = logits[:, -1, :]
+            nxt = jnp.argmax(rows, axis=-1)
+            return nxt.astype(jnp.int32), rows if keep else None, cache
 
         if self.mesh is not None:
             rep = jax.sharding.NamedSharding(
@@ -195,7 +210,7 @@ class ContinuousBatchingEngine:
             self._insert = jax.jit(insert_fn, donate_argnums=(0,),
                                    out_shardings=self._c_sh)
             self._decode = jax.jit(decode_fn, donate_argnums=(1,),
-                                   out_shardings=(rep, self._c_sh))
+                                   out_shardings=(rep, rep, self._c_sh))
         else:
             self._prefill = jax.jit(prefill_fn)
             self._insert = jax.jit(insert_fn, donate_argnums=(0,))
@@ -244,17 +259,17 @@ class ContinuousBatchingEngine:
             lane = free[0]
             # pad the prompt to whole pages: one prefill compilation per
             # page-count bucket, and the cache slice lands page-aligned
-            Ppad = min(self.max_seq, self.page_size * self._pages_for(P))
-            toks = np.zeros((1, Ppad), np.int32)
-            toks[0, :P] = np.asarray(req.prompt, np.int32)
-            tok, sl = self._prefill(self.params, jnp.asarray(toks),
-                                    jnp.asarray(P, jnp.int32))
+            toks = page_padded(req.prompt, self.page_size, self.max_seq)
+            tok, row, sl = self._prefill(self.params, jnp.asarray(toks),
+                                         jnp.asarray(P, jnp.int32))
             self.cache = self._insert(self.cache, sl,
                                       jnp.asarray(lane, jnp.int32))
             first = int(tok)
             self.free_pages -= pages
             self.lanes[lane] = _Lane(req=req, length=P, pages=pages,
                                      out=[first], t_admit=time.perf_counter())
+            if self.keep_logits:
+                self.lanes[lane].logits.append(np.asarray(row))
             self._count("serve.requests_admitted")
             self._finish_if_done(lane, first)
 
@@ -269,6 +284,8 @@ class ContinuousBatchingEngine:
             return
         r: Dict[str, Any] = {"id": lane.req.rid, "tokens": list(lane.out),
                              "n_prompt": len(lane.req.prompt)}
+        if self.keep_logits:
+            r["logits"] = np.stack(lane.logits)
         if self.certset is not None:
             r["certificate"] = dict(self.certset.error_bars(),
                                     params_digest=self.certset.params_digest)
@@ -291,9 +308,9 @@ class ContinuousBatchingEngine:
                 tokens[i] = lane.out[-1]
                 offsets[i] = lane.length
         t0 = time.perf_counter()
-        nxt, self.cache = self._decode(self.params, self.cache,
-                                       jnp.asarray(tokens),
-                                       jnp.asarray(offsets))
+        nxt, rows, self.cache = self._decode(self.params, self.cache,
+                                             jnp.asarray(tokens),
+                                             jnp.asarray(offsets))
         nxt = jax.block_until_ready(nxt)
         dt = time.perf_counter() - t0
         self.steps += 1
@@ -306,10 +323,13 @@ class ContinuousBatchingEngine:
                                       dt)
             self._count("serve.tokens", len(active))
         nxt = np.asarray(nxt)
+        rows = None if rows is None else np.asarray(rows)
         for i in active:
             lane = self.lanes[i]
             lane.length += 1
             lane.out.append(int(nxt[i]))
+            if rows is not None:
+                lane.logits.append(rows[i])
             self._finish_if_done(i, int(nxt[i]))
         return True
 
@@ -335,21 +355,37 @@ class ContinuousBatchingEngine:
         return self.responses
 
 
+def page_padded(prompt: Sequence[int], page_size: int,
+                max_seq: int) -> np.ndarray:
+    """``[1, Ppad]`` token ids: the prompt zero-padded to whole pages (at
+    most ``max_seq``). The pad columns sit after every real position, so
+    causal masking keeps them out of the real rows."""
+    P = len(prompt)
+    toks = np.zeros((1, min(max_seq, page_size * -(-P // page_size))),
+                    np.int32)
+    toks[0, :P] = np.asarray(prompt, np.int32)
+    return toks
+
+
 def reference_generate(arch_cfg, sc: serve.ServeConfig, params,
                        prompt: Sequence[int], max_new_tokens: int, *,
-                       max_seq: int, eos_id: int = -1) -> List[int]:
-    """Single-device eager reference: batch 1, unpadded prefill, unrolled
-    per-layer backend, no mesh — the bitwise oracle the engine must match.
-    ``max_seq`` must equal the engine's (the cache width is part of the
-    masked-softmax shape)."""
+                       max_seq: int, page_size: int,
+                       eos_id: int = -1) -> List[int]:
+    """Single-device eager reference: batch 1, unrolled per-layer backend,
+    no mesh — the bitwise oracle the engine must match. ``max_seq`` and
+    ``page_size`` must equal the engine's: the cache width is part of the
+    masked-softmax shape, and the prefill runs at the engine's page-padded
+    shape (pad columns masked, the write index pinned to the true
+    length)."""
     bk = make_backend(sc, mesh=None, unrolled=True)
     cache = T.init_cache(arch_cfg, 1, max_seq, jnp.float32,
                          per_lane_idx=True)
-    toks = jnp.asarray(np.asarray(prompt, np.int32)[None, :])
+    toks = jnp.asarray(page_padded(prompt, page_size, max_seq))
     logits, cache = T.forward(bk, params, arch_cfg, toks, cache=cache,
                               q_offset=jnp.zeros((1,), jnp.int32))
     P = len(prompt)
-    tok = int(jnp.argmax(logits[0, -1, :]))
+    cache = {**cache, "idx": jnp.full_like(cache["idx"], P)}
+    tok = int(jnp.argmax(logits[0, P - 1, :]))
     out = [tok]
     while (tok != eos_id and len(out) < max_new_tokens
            and P + len(out) < max_seq):
@@ -401,6 +437,7 @@ def main(argv=None):
     ap.add_argument("--metrics", default=None, metavar="OUT.JSONL")
     ap.add_argument("--prom", default=None, metavar="OUT.PROM")
     args = ap.parse_args(argv)
+    use_compile_cache()
     if ((args.certify_mixed or args.certify_formats
          or args.certify_k_max is not None) and args.certificates is None):
         ap.error("--certify-* require --certificates STORE_DIR")
@@ -467,7 +504,8 @@ def main(argv=None):
             got = next(r["tokens"] for r in responses if r["id"] == req.rid)
             want = reference_generate(arch_cfg, sc, params, req.prompt,
                                       req.max_new_tokens,
-                                      max_seq=args.max_seq)
+                                      max_seq=args.max_seq,
+                                      page_size=args.page_size)
             if got != want:
                 bad.append((req.rid, got, want))
         if bad:

@@ -16,15 +16,23 @@ from __future__ import annotations
 import jax
 
 
+def _auto_mesh(shape, axes, devices=None):
+    # Auto axes: sharding is steered by with_sharding_constraint and
+    # NamedSharding placements, never by explicit-axis typing
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """A 1×1 mesh on the real local device — smoke tests of the pjit path."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _auto_mesh((1, 1), ("data", "model"))
 
 
 def make_serving_mesh(data: int = None, model: int = None, *,
@@ -42,7 +50,6 @@ def make_serving_mesh(data: int = None, model: int = None, *,
     subset (the benchmark's mesh-size sweep takes prefixes of
     ``jax.devices()``).
     """
-    import numpy as np
     devs = list(devices if devices is not None else jax.devices())
     n = len(devs)
     if data is None and model is None:
@@ -54,9 +61,8 @@ def make_serving_mesh(data: int = None, model: int = None, *,
         assert n % data == 0, (n, data)
         model = n // data
     assert data * model <= n, (data, model, n)
-    grid = np.array(devs[: data * model]).reshape(data, model)
-    from jax.sharding import Mesh
-    return Mesh(grid, ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"),
+                      devices=devs[: data * model])
 
 
 def device_count() -> int:

@@ -34,6 +34,7 @@ from repro.core.backend import JOps, UnrolledLayerLoop  # noqa: F401 — the
 from repro.models import transformer as T
 from repro.parallel import sharding as sh
 from repro.launch import mesh as meshlib
+from repro.launch.jitcache import use_compile_cache
 
 log = obs.get_logger("serve")
 
@@ -115,7 +116,8 @@ class QuantJOps(JOps):
         from repro.core.quantize import _quantize_normal
         aq = _quantize_normal(a.astype(jnp.float32), self._k)
         bq = _quantize_normal(b.astype(jnp.float32), self._k)
-        out = jnp.matmul(aq, bq, preferred_element_type=jnp.float32)
+        out = jnp.matmul(aq, bq, precision=jax.lax.Precision.HIGHEST,
+                         preferred_element_type=jnp.float32)
         _emit_health(self, out, self._k)
         return _quantize_normal(out, self._k).astype(self.compute_dtype)
 
@@ -330,13 +332,41 @@ class FormatQuantJOps(_SuffixLanes, JOps):
     # machinery as matmul, so layer*/attn sub-lanes apply). Class-level so
     # tests can force the composed einsum/softmax path off.
     use_flash_decode = True
+    # None: the Pallas kernels on a TPU, their eager mirrors elsewhere.
+    # A check that compares the two on the chip sets False on an instance.
+    force_kernel = None
+
+    def _axis(self, name: str, dim: int):
+        """``name`` if that mesh axis splits ``dim`` evenly, else None."""
+        size = 1 if self.mesh is None else meshlib.axis_size(self.mesh, name)
+        return name if size > 1 and dim % size == 0 else None
+
+    def _per_shard(self, fn, args, in_specs, out_spec):
+        """Run ``fn`` once per device of a multi-device mesh. XLA cannot
+        partition a Mosaic kernel, so the call goes through shard_map: each
+        device takes its lanes ("data") and its output columns or KV heads
+        ("model"). No contraction is split, so the bits are one device's."""
+        if self.mesh is None or self.mesh.devices.size == 1:
+            return fn(*args)
+        return jax.shard_map(fn, mesh=self.mesh,
+                             in_specs=tuple(P(*s) for s in in_specs),
+                             out_specs=P(*out_spec), check_vma=False)(*args)
 
     def matmul(self, a, b):
         from repro.kernels.quant_matmul import quant_matmul_format_dispatch
         fmt = self._current_fmt()
-        out = quant_matmul_format_dispatch(a, b, fmt,
-                                           has_subnormals=self.has_subnormals,
-                                           saturating=self.saturating)
+
+        def gemm(a, b, fmt):
+            return quant_matmul_format_dispatch(
+                a, b, fmt, has_subnormals=self.has_subnormals,
+                saturating=self.saturating, force_kernel=self.force_kernel)
+
+        lanes = self._axis("data", a.shape[0])
+        cols = self._axis("model", b.shape[-1])
+        rest = (None,) * (a.ndim - 2)
+        out = self._per_shard(gemm, (a, b, fmt),
+                              ((lanes, *rest, None), (None, cols), (None,)),
+                              (lanes, *rest, cols))
         _emit_health(self, out, fmt[0], fmt[1], fmt[2])
         return out.astype(self.compute_dtype)
 
@@ -345,9 +375,19 @@ class FormatQuantJOps(_SuffixLanes, JOps):
             return None
         from repro.kernels.flash_decode import certified_decode_attention
         fmt = self._current_fmt()
-        out = certified_decode_attention(q, k, v, lengths, fmt,
-                                         has_subnormals=self.has_subnormals,
-                                         saturating=self.saturating)
+
+        def attend(q, k, v, lengths, fmt):
+            return certified_decode_attention(
+                q, k, v, lengths, fmt, has_subnormals=self.has_subnormals,
+                saturating=self.saturating, force_kernel=self.force_kernel)
+
+        lanes = self._axis("data", q.shape[0])
+        heads = self._axis("model", q.shape[1])
+        out = self._per_shard(
+            attend, (q, k, v, lengths, fmt),
+            ((lanes, heads, None, None), (lanes, None, heads, None),
+             (lanes, None, heads, None), (lanes,), (None,)),
+            (lanes, heads, None, None))
         return out.astype(self.compute_dtype)
 
     def layer_loop(self, fn, stacked_params, x, n_layers: int, aux=None):
@@ -540,6 +580,7 @@ def main(argv=None):
                          "trace span, per-jit compile-time and jaxpr-size "
                          "gauges; render with `python -m repro.obs report`")
     args = ap.parse_args(argv)
+    use_compile_cache()
     if args.trace:
         obs.configure(path=args.trace, program="repro.launch.serve",
                       argv=argv)

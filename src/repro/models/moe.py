@@ -107,7 +107,7 @@ def _ep_experts(bk, x, p, n_experts, top_k, act, capacity_factor,
     einsums forced XLA into parameter/token-sized all-gathers.
     """
     import jax
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = bk.mesh

@@ -18,8 +18,8 @@ scopes whose bits the greedy descent spent latency-blind) made explicit.
 The fitted model exports as JSON (``CostModel.to_dict``/``save_json``) so
 the certify CLI's ``--cost-report`` pass and a future latency-objective
 greedy descent read the same artifact. Hardware peaks live here too —
-:data:`TPU_POD_CHIP` is the single source for the analytic roofline terms
-``benchmarks/roofline.py`` prints.
+:data:`PEAKS`, keyed by ``device_kind``, is the single source for the
+analytic roofline terms ``benchmarks/roofline.py`` prints.
 """
 from __future__ import annotations
 
@@ -54,9 +54,32 @@ class Hardware:
         return dataclasses.asdict(self)
 
 
-#: the single-pod chip the analytic roofline (benchmarks/roofline.py) uses:
-#: 197 TFLOP/s bf16 MXU, 819 GB/s HBM, 50 GB/s/link ICI
-TPU_POD_CHIP = Hardware("tpu-pod-chip", 197e12, 819e9, 50e9)
+#: per-chip peaks keyed by ``jax.Device.device_kind``, each with its source.
+#: TPU v5e (device_kind "TPU v5 lite"): Google Cloud documentation, "TPU
+#: v5e" — 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s of chip-to-chip
+#: interconnect over four links (50 GB/s each).
+PEAKS: Dict[str, Hardware] = {
+    "TPU v5 lite": Hardware("TPU v5 lite", 197e12, 819e9, 50e9),
+}
+
+
+def hardware_for(device_kind: str) -> Hardware:
+    """The peaks of the chip JAX names ``device_kind``. A device that is
+    not in :data:`PEAKS` is an error, never a default: a roofline against
+    another chip's peaks is a wrong number, not an approximate one."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak rates for device kind {device_kind!r}; add them to "
+            f"repro.obs.costmodel.PEAKS with their source") from None
+
+
+def local_hardware() -> Optional[Hardware]:
+    """Peaks of the first local device, or None for a device that is not
+    in :data:`PEAKS` (the CPU): callers then report no roofline share."""
+    import jax
+    return PEAKS.get(jax.devices()[0].device_kind)
 
 
 def format_bits(k: int, emax: Optional[int] = None,
@@ -110,7 +133,7 @@ class CostModel:
 
     alpha: Dict[str, float]
     beta: Dict[str, float]
-    hardware: Hardware = TPU_POD_CHIP
+    hardware: Optional[Hardware] = None     # the chip the rates came from
     meta: Dict[str, Any] = dataclasses.field(default_factory=dict)
 
     # -- kernel resolution --------------------------------------------------
@@ -157,7 +180,7 @@ class CostModel:
             "schema": 1,
             "alpha_flops_per_s": dict(self.alpha),
             "beta_bytes_per_s": dict(self.beta),
-            "hardware": self.hardware.to_dict(),
+            "hardware": self.hardware and self.hardware.to_dict(),
             "meta": dict(self.meta),
         }
 
@@ -166,7 +189,7 @@ class CostModel:
         hw = d.get("hardware") or {}
         return cls(alpha=dict(d["alpha_flops_per_s"]),
                    beta=dict(d["beta_bytes_per_s"]),
-                   hardware=Hardware(**hw) if hw else TPU_POD_CHIP,
+                   hardware=Hardware(**hw) if hw else None,
                    meta=dict(d.get("meta") or {}))
 
     def save_json(self, path: str):
@@ -193,7 +216,7 @@ def _median(xs: Sequence[float]) -> float:
 
 
 def fit_cost_model(records: Sequence[Dict[str, Any]],
-                   hardware: Hardware = TPU_POD_CHIP) -> CostModel:
+                   hardware: Optional[Hardware] = None) -> CostModel:
     """Fit (α, β) per kernel from measured profile records.
 
     Each record needs ``kernel``, ``median_s``, ``flops``, ``bytes`` — the

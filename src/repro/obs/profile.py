@@ -31,7 +31,7 @@ from __future__ import annotations
 import time
 from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence
 
-from .costmodel import Hardware, TPU_POD_CHIP, format_bits
+from .costmodel import Hardware, format_bits, local_hardware
 
 BYTES_F32 = 4  # the emulation's carrier width: everything streams as f32
 
@@ -108,42 +108,38 @@ def time_compile(jitted, *args) -> Dict[str, Any]:
 # analytic terms per kernel invocation
 # ---------------------------------------------------------------------------
 
-def gemm_terms(M: int, K: int, N: int, bits: float = 32.0,
-               hw: Hardware = TPU_POD_CHIP) -> Dict[str, Any]:
-    """Analytic roofline terms of one [M,K]@[K,N] GEMM at ``bits``/value
-    storage: flops = 2·M·K·N, bytes = operands in + result out (each value
-    touched once — the blocked kernel's VMEM residency makes this the
-    floor), intensity = flops/bytes vs the hardware ridge."""
-    flops = 2.0 * M * K * N
-    bytes_moved = (M * K + K * N + M * N) * bits / 8.0
-    intensity = flops / bytes_moved
-    compute_s = flops / hw.peak_flops
-    memory_s = bytes_moved / hw.hbm_bytes_per_s
-    return {
-        "flops": flops, "bytes": bytes_moved, "intensity": intensity,
-        "compute_s": compute_s, "memory_s": memory_s,
-        "roofline_s": max(compute_s, memory_s),
-        "bound": "memory" if memory_s >= compute_s else "compute",
-    }
+def _terms(flops: float, bytes_moved: float,
+           hw: Optional[Hardware]) -> Dict[str, Any]:
+    terms = {"flops": flops, "bytes": bytes_moved,
+             "intensity": flops / bytes_moved}
+    if hw is not None:          # a roofline needs the chip's peaks
+        compute_s = flops / hw.peak_flops
+        memory_s = bytes_moved / hw.hbm_bytes_per_s
+        terms.update(compute_s=compute_s, memory_s=memory_s,
+                     roofline_s=max(compute_s, memory_s),
+                     bound="memory" if memory_s >= compute_s else "compute")
+    return terms
+
+
+def gemm_terms(M: int, K: int, N: int, bits: float, *,
+               hw: Optional[Hardware]) -> Dict[str, Any]:
+    """Analytic terms of one [M,K]@[K,N] GEMM at ``bits``/value storage:
+    flops = 2·M·K·N, bytes = operands in + result out (each value touched
+    once — the blocked kernel's VMEM residency makes this the floor),
+    intensity = flops/bytes; with ``hw``, also the roofline times at its
+    peaks and the side of its ridge the GEMM sits on."""
+    return _terms(2.0 * M * K * N, (M * K + K * N + M * N) * bits / 8.0, hw)
 
 
 def flash_decode_terms(B: int, S: int, K: int, G: int, D: int,
-                       bits: float = 32.0,
-                       hw: Hardware = TPU_POD_CHIP) -> Dict[str, Any]:
+                       bits: float, *,
+                       hw: Optional[Hardware]) -> Dict[str, Any]:
     """Analytic terms of one flash-decode call: QK^T + PV are 2·2·B·K·G·S·D
     flops; bytes stream the KV cache once (the whole point of the online
     softmax) plus q in / o out."""
-    flops = 4.0 * B * K * G * S * D
-    bytes_moved = (2.0 * B * S * K * D + 2.0 * B * K * G * D) * bits / 8.0
-    intensity = flops / bytes_moved
-    compute_s = flops / hw.peak_flops
-    memory_s = bytes_moved / hw.hbm_bytes_per_s
-    return {
-        "flops": flops, "bytes": bytes_moved, "intensity": intensity,
-        "compute_s": compute_s, "memory_s": memory_s,
-        "roofline_s": max(compute_s, memory_s),
-        "bound": "memory" if memory_s >= compute_s else "compute",
-    }
+    return _terms(4.0 * B * K * G * S * D,
+                  (2.0 * B * S * K * D + 2.0 * B * K * G * D) * bits / 8.0,
+                  hw)
 
 
 # ---------------------------------------------------------------------------
@@ -164,17 +160,20 @@ ALL_KERNELS = ("matmul_baseline", "quant_matmul_dynamic_k",
 def _row(kernel: str, terms: Dict[str, Any], timing: Dict[str, float],
          **extra) -> Dict[str, Any]:
     med = timing["median_s"]
-    return {
+    row = {
         "kernel": kernel,
         "median_s": med, "min_s": timing["min_s"], "reps": timing["reps"],
         "flops": terms["flops"], "bytes": terms["bytes"],
         "intensity": terms["intensity"],
-        "roofline_s": terms["roofline_s"], "bound": terms["bound"],
         "achieved_flops_per_s": terms["flops"] / med if med > 0 else 0.0,
         "achieved_bytes_per_s": terms["bytes"] / med if med > 0 else 0.0,
-        "roofline_frac": terms["roofline_s"] / med if med > 0 else 0.0,
         **extra,
     }
+    if "roofline_s" in terms:
+        row.update(roofline_s=terms["roofline_s"], bound=terms["bound"],
+                   roofline_frac=terms["roofline_s"] / med if med > 0
+                   else 0.0)
+    return row
 
 
 def profile_kernels(gemm_shapes: Iterable[tuple] = DEFAULT_GEMM_SHAPES,
@@ -185,17 +184,21 @@ def profile_kernels(gemm_shapes: Iterable[tuple] = DEFAULT_GEMM_SHAPES,
                     include: Sequence[str] = ALL_KERNELS,
                     reps: int = 5, warmup: int = 2,
                     interpret: Optional[bool] = None,
-                    hw: Hardware = TPU_POD_CHIP) -> List[Dict[str, Any]]:
+                    hw: Optional[Hardware] = None) -> List[Dict[str, Any]]:
     """Time every certified kernel across the sweep; one row per point.
 
     ``blocks`` — (bm, bn, bk) Pallas tile candidates for the format kernel
     (default: :func:`repro.kernels.quant_matmul.block_candidates` per
     shape, the autotune axis); ``interpret`` default follows the backend
-    (interpret off-TPU). Rows are what ``fit_cost_model`` and the
+    (interpret off-TPU); ``hw`` defaults to the local chip's peaks, and
+    on a device without a :data:`repro.obs.costmodel.PEAKS` entry the rows
+    carry no roofline terms (no ``roofline_s``/``bound``/
+    ``roofline_frac``). Rows are what ``fit_cost_model`` and the
     ``BENCH_kernels.json`` trajectory consume."""
     import jax
     import jax.numpy as jnp
     from repro import obs
+    hw = hw or local_hardware()
     from repro.kernels.quant_matmul import (block_candidates, quant_matmul,
                                             quant_matmul_dynamic_k,
                                             quant_matmul_format)
@@ -211,7 +214,7 @@ def profile_kernels(gemm_shapes: Iterable[tuple] = DEFAULT_GEMM_SHAPES,
         x = jax.random.normal(kx, (M, K), jnp.float32)
         w = jax.random.normal(kw, (K, N), jnp.float32)
         shape = {"M": M, "K": K, "N": N, "shape": f"{M}x{K}x{N}"}
-        terms32 = gemm_terms(M, K, N, 32.0, hw)
+        terms32 = gemm_terms(M, K, N, 32.0, hw=hw)
 
         if "matmul_baseline" in include:
             f = jax.jit(lambda a, b: jnp.matmul(
@@ -275,7 +278,7 @@ def profile_kernels(gemm_shapes: Iterable[tuple] = DEFAULT_GEMM_SHAPES,
             bs = min(128, S)
             f = jax.jit(lambda *a: flash_decode_attention(
                 *a, block_s=bs, interpret=interpret))
-            terms = flash_decode_terms(B, S, Kh, G, D, 32.0, hw)
+            terms = flash_decode_terms(B, S, Kh, G, D, 32.0, hw=hw)
             with obs.span("profile.kernel", kernel="flash_decode",
                           shape=f"B{B}S{S}K{Kh}G{G}D{D}"):
                 t = measure(f, q, kc, vc, lengths, reps=reps, warmup=warmup)

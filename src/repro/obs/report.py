@@ -159,14 +159,17 @@ def render_kernel_table(entries: List[Dict[str, Any]],
             delta = f"{(r['median_s'] / prev['median_s'] - 1.0):+.0%}"
         else:
             delta = "-"
+        # rows from a device without peaks carry no roofline terms
+        roof = (f"{r['roofline_s'] * 1e6:.3f}" if "roofline_s" in r
+                else "-")
         lines.append(
             f"{r.get('kernel', '?'):<24} {r.get('shape', '?'):<14} "
             f"{_fmt_label(r):>7} {_block_label(r):>12} "
             f"{r['median_s'] * 1e6:>10.1f} "
             f"{r.get('achieved_flops_per_s', 0) / 1e9:>9.2f} "
             f"{r.get('intensity', 0):>7.2f} "
-            f"{r.get('roofline_s', 0) * 1e6:>9.3f} "
-            f"{r.get('bound', '?'):>7} {delta:>7}")
+            f"{roof:>9} "
+            f"{r.get('bound', '-'):>7} {delta:>7}")
     serving = last.get("serving")
     if serving:
         lines.append("")

@@ -77,7 +77,7 @@ def compressed_psum_grads(local_grads, mesh, dp_axes=("data",), block: int = 256
     dequantised with the summed scales upper bound. Bytes on the wire: 1/4
     of f32 (+ 1/block scale overhead).
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     def reduce_one(g):
         def f(x):

@@ -193,16 +193,21 @@ def test_flash_decode_full_length():
 
 @pytest.mark.parametrize("fmt", [(8, 15, -14), (4, 8, -6), (11, 30, -30)])
 @pytest.mark.parametrize("lead", [(12,), (2, 5)])
-def test_quant_matmul_format_dispatch_bitwise(fmt, lead):
+@pytest.mark.parametrize("K", [40, 1536], ids=["one_k_block", "three"])
+def test_quant_matmul_format_dispatch_bitwise(fmt, lead, K):
     """The serving dispatch (FormatQuantJOps.matmul) must be bitwise
-    IDENTICAL through both of its arms: eager ref on CPU, the single-K-step
-    scalar-prefetch Pallas kernel on TPU (interpret mode here). Batched
-    leading dims flatten through the kernel and restore."""
-    from repro.kernels.quant_matmul import (quant_matmul_format_dispatch,
+    IDENTICAL through both of its arms: eager ref on CPU, the scalar-
+    prefetch Pallas kernel on TPU (interpret mode here) — also when the
+    contraction spans several format_block_k blocks, which the reference
+    sums in the kernel's order. Batched leading dims flatten through the
+    kernel and restore."""
+    from repro.kernels.quant_matmul import (format_block_k,
+                                            quant_matmul_format_dispatch,
                                             quant_matmul_format_ref)
+    assert K // format_block_k(K) == (1 if K == 40 else 3)
     rng = np.random.RandomState(fmt[0] + len(lead))
-    x = jnp.asarray(rng.randn(*lead, 40).astype(np.float32))
-    w = jnp.asarray(rng.randn(40, 24).astype(np.float32))
+    x = jnp.asarray(rng.randn(*lead, K).astype(np.float32))
+    w = jnp.asarray(rng.randn(K, 24).astype(np.float32))
     f = jnp.asarray(fmt, jnp.int32)
     want = quant_matmul_format_ref(x, w, f)
     eager = quant_matmul_format_dispatch(x, w, f, force_kernel=False)

@@ -19,6 +19,9 @@ from repro.obs import costmodel as CM
 from repro.obs import profile as P
 from repro.obs.report import render_kernel_table
 
+# analytic terms are priced against an explicit chip; the CPU has no peaks
+V5E = CM.hardware_for("TPU v5 lite")
+
 from _hyp import given, st
 
 
@@ -68,8 +71,24 @@ def test_time_compile_returns_runnable_executable():
     np.testing.assert_allclose(np.asarray(r["compiled"](x)), np.eye(4))
 
 
+def test_peaks_are_keyed_by_device_kind():
+    assert V5E.peak_flops == 197e12 and V5E.hbm_bytes_per_s == 819e9
+    with pytest.raises(ValueError, match="no peak rates"):
+        CM.hardware_for("cpu")
+    # no silent default: off the chips in the table the rows keep their
+    # measured and analytic terms, and report no roofline share
+    assert CM.local_hardware() is CM.PEAKS.get(jax.devices()[0].device_kind)
+    if CM.local_hardware() is None:
+        (row,) = P.profile_kernels(gemm_shapes=((16, 16, 16),),
+                                   include=("matmul_baseline",),
+                                   reps=1, warmup=1)
+        assert row["flops"] == 2.0 * 16 ** 3 and row["median_s"] > 0
+        assert not {"roofline_s", "roofline_frac", "bound"} & set(row)
+        assert "bound" not in P.gemm_terms(16, 16, 16, 32.0, hw=None)
+
+
 def test_gemm_terms_math():
-    t = P.gemm_terms(128, 256, 64, bits=8.0)
+    t = P.gemm_terms(128, 256, 64, bits=8.0, hw=V5E)
     assert t["flops"] == 2.0 * 128 * 256 * 64
     assert t["bytes"] == (128 * 256 + 256 * 64 + 128 * 64) * 1.0
     assert t["intensity"] == pytest.approx(t["flops"] / t["bytes"])
@@ -78,11 +97,12 @@ def test_gemm_terms_math():
     # small GEMMs sit on the memory side of the TPU ridge
     assert t["bound"] == "memory"
     # narrower storage moves the SAME flops with fewer bytes
-    assert P.gemm_terms(128, 256, 64, bits=32.0)["bytes"] == 4 * t["bytes"]
+    assert P.gemm_terms(128, 256, 64, bits=32.0, hw=V5E)["bytes"] == \
+        4 * t["bytes"]
 
 
 def test_flash_decode_terms_math():
-    t = P.flash_decode_terms(2, 256, 2, 2, 64, bits=32.0)
+    t = P.flash_decode_terms(2, 256, 2, 2, 64, bits=32.0, hw=V5E)
     assert t["flops"] == 4.0 * 2 * 2 * 2 * 256 * 64
     assert t["bytes"] == (2 * 2 * 256 * 2 * 64 + 2 * 2 * 2 * 2 * 64) * 4.0
     assert t["bound"] == "memory"   # decode attention streams the KV cache
@@ -106,7 +126,7 @@ def test_profile_kernels_rows_and_spans():
     rows = P.profile_kernels(
         gemm_shapes=((16, 16, 16),), ks=(8,),
         include=("matmul_baseline", "quant_matmul_dynamic_k"),
-        reps=2, warmup=1)
+        reps=2, warmup=1, hw=V5E)
     assert [r["kernel"] for r in rows] == ["matmul_baseline",
                                            "quant_matmul_dynamic_k"]
     for r in rows:
@@ -124,7 +144,7 @@ def test_profile_kernels_pallas_format_point():
     (row,) = P.profile_kernels(
         gemm_shapes=((16, 16, 16),), formats=((4, 8, -6),),
         blocks=((16, 16, 16),), include=("quant_matmul_format",),
-        reps=1, warmup=1)
+        reps=1, warmup=1, hw=V5E)
     assert row["kernel"] == "quant_matmul_format"
     assert row["interpret"] == (jax.default_backend() != "tpu")
     assert row["block"] == [16, 16, 16]
@@ -149,7 +169,8 @@ def test_format_bits_and_scope_class():
 def _toy_model(alpha_gemm=1e9, beta_gemm=1e8):
     return CM.CostModel(
         alpha={"quant_matmul_format": alpha_gemm, "flash_decode": 5e8},
-        beta={"quant_matmul_format": beta_gemm, "flash_decode": 2e8})
+        beta={"quant_matmul_format": beta_gemm, "flash_decode": 2e8},
+        hardware=V5E)
 
 
 def test_fit_cost_model_median_rates():
@@ -267,7 +288,7 @@ def test_certificate_cost_report_uses_serving_map():
 def _kernel_entry(median_a=1e-3, median_b=1e-3):
     return {
         "kind": "kernel_bench", "backend": "cpu", "interpret": True,
-        "hardware": CM.TPU_POD_CHIP.to_dict(),
+        "hardware": V5E.to_dict(),
         "rows": [
             {"kernel": "matmul_baseline", "shape": "128x128x128",
              "median_s": median_a, "flops": 2.0 * 128 ** 3,

@@ -37,11 +37,15 @@ def _requests(n, seed=0, plen_lo=5, plen_hi=12, max_new=5, stride=1):
             for i in range(n)]
 
 
+PAGE = 8      # every engine below pages its cache in 8-token pages
+
+
 def _assert_matches_reference(sc, params, responses, reqs, max_seq):
     for req in reqs:
         got = next(r["tokens"] for r in responses if r["id"] == req.rid)
         want = reference_generate(CFG, sc, params, req.prompt,
-                                  req.max_new_tokens, max_seq=max_seq)
+                                  req.max_new_tokens, max_seq=max_seq,
+                                  page_size=PAGE)
         assert got == want, (req.rid, got, want)
 
 
@@ -106,7 +110,8 @@ def test_format_fused_decode_actually_engages(params, monkeypatch):
     sc = serve.ServeConfig(arch="qwen2_7b", batch=1, max_seq=48,
                            precision_layer_format=fmt)
     prompt = list(np.random.RandomState(3).randint(0, CFG.vocab, 6))
-    out = reference_generate(CFG, sc, params, prompt, 6, max_seq=48)
+    out = reference_generate(CFG, sc, params, prompt, 6, max_seq=48,
+                             page_size=PAGE)
     assert len(out) == 6
     # eager unrolled reference: one hook call per layer per decode step
     assert len(calls) == CFG.n_layers * (len(out) - 1)
@@ -130,12 +135,16 @@ def test_lane_recycling_and_page_accounting(params):
 def test_eos_recycles_lane_early(params):
     sc = serve.ServeConfig(arch="qwen2_7b", batch=1, max_seq=48)
     prompt = list(np.random.RandomState(5).randint(0, CFG.vocab, 6))
-    free_run = reference_generate(CFG, sc, params, prompt, 8, max_seq=48)
-    eos = free_run[2]          # a token the model will actually emit
+    free_run = reference_generate(CFG, sc, params, prompt, 8, max_seq=48,
+                                  page_size=PAGE)
+    # a token the model will actually emit, first emitted mid-stream
+    stop = next(i for i in range(1, len(free_run))
+                if free_run[i] not in free_run[:i])
     eng = ContinuousBatchingEngine(CFG, sc, params, n_lanes=1, max_seq=48,
-                                   page_size=8, eos_id=eos)
+                                   page_size=8, eos_id=free_run[stop])
     [resp] = eng.run([Request(rid=0, prompt=prompt, max_new_tokens=8)])
-    assert resp["tokens"] == free_run[:3]             # stopped AT the eos
+    assert resp["tokens"] == free_run[:stop + 1]      # stopped AT the eos
+    assert len(resp["tokens"]) < 8
     assert eng.free_pages == eng.total_pages
 
 
@@ -197,27 +206,40 @@ def test_responses_carry_certificate_bars(params):
         assert r["certificate"]["params_digest"] == "deadbeef"
 
 
-def test_padded_prefill_bitwise_equals_unpadded(params):
-    """The linchpin of batched prefill-insert: padding a prompt to a whole
-    number of pages must not change the last real row's logits (causal
-    masking makes pad columns contribute exact -1e9-masked zeros) nor the
-    first P cache positions."""
+def test_pad_contents_do_not_reach_real_rows(params):
+    """The linchpin of batched prefill-insert: what sits in the pad columns
+    of a page-padded prompt must not reach the real rows' logits nor the
+    first P cache positions — causal masking turns pad columns into exact
+    zeros, so two paddings of one prompt agree bitwise. Against the
+    UNPADDED prompt the agreement is to f32 rounding only: XLA:CPU blocks
+    a 6-row and a 16-row GEMM differently, which is why the engine's
+    reference prefills at the engine's own padded shape."""
     bk = make_backend(serve.ServeConfig(arch="qwen2_7b", batch=1,
                                         max_seq=32))
     rng = np.random.RandomState(8)
     toks = rng.randint(0, CFG.vocab, 6)
-    padded = np.zeros(16, np.int32)
-    padded[:6] = toks
-    c1 = T.init_cache(CFG, 1, 32, jnp.float32, per_lane_idx=True)
-    c2 = T.init_cache(CFG, 1, 32, jnp.float32, per_lane_idx=True)
+    zero_pad = np.zeros(16, np.int32)
+    zero_pad[:6] = toks
+    junk_pad = rng.randint(0, CFG.vocab, 16).astype(np.int32)
+    junk_pad[:6] = toks
     z = jnp.zeros((1,), jnp.int32)
-    lg1, c1 = T.forward(bk, params, CFG, jnp.asarray(toks[None]),
-                        cache=c1, q_offset=z)
-    lg2, c2 = T.forward(bk, params, CFG, jnp.asarray(padded[None]),
-                        cache=c2, q_offset=z)
+
+    def prefill(t):
+        c = T.init_cache(CFG, 1, 32, jnp.float32, per_lane_idx=True)
+        return T.forward(bk, params, CFG, jnp.asarray(t[None]), cache=c,
+                         q_offset=z)
+
+    lg1, c1 = prefill(zero_pad)
+    lg2, c2 = prefill(junk_pad)
     assert bool(jnp.array_equal(lg1[0, :6], lg2[0, :6]))
     assert bool(jnp.array_equal(c1["k"][:, :, :6], c2["k"][:, :, :6]))
     assert bool(jnp.array_equal(c1["v"][:, :, :6], c2["v"][:, :, :6]))
+    lg0, c0 = prefill(toks)
+    np.testing.assert_allclose(lg0[0], lg1[0, :6], rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(c0["k"][:, :, :6], c1["k"][:, :, :6],
+                               rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(c0["v"][:, :, :6], c1["v"][:, :, :6],
+                               rtol=1e-5, atol=1e-6)
 
 
 def test_engine_on_explicit_mesh(params):
@@ -233,3 +255,35 @@ def test_engine_on_explicit_mesh(params):
     responses = eng.run(reqs)
     assert len(responses) == 3
     _assert_matches_reference(sc, params, responses, reqs, 32)
+
+
+def test_plain_reference_matches_backend_forward(params):
+    """The plain f32 reference the chip smoke compares against computes
+    the same function as the backend-generic transformer (no cache)."""
+    from repro.models.reference import dense_forward
+    toks = jnp.asarray(np.random.RandomState(11).randint(0, CFG.vocab,
+                                                         (2, 12)))
+    want, _ = T.forward(make_backend(serve.ServeConfig()), params, CFG, toks)
+    got = dense_forward(params, CFG, toks)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-6)
+
+
+def test_kept_logits_match_plain_reference(params):
+    """keep_logits: row t of a response's logits is the distribution its
+    token t was taken from — prefill row, then decode rows — and agrees
+    with the plain reference run teacher-forced on the served tokens."""
+    from repro.models.reference import dense_forward
+    sc = serve.ServeConfig(arch="qwen2_7b", batch=2, max_seq=48)
+    eng = ContinuousBatchingEngine(CFG, sc, params, n_lanes=2, max_seq=48,
+                                   page_size=PAGE, keep_logits=True)
+    reqs = _requests(3, seed=12, max_new=4)
+    for r in eng.run(reqs):
+        req = reqs[r["id"]]
+        assert r["logits"].shape == (len(r["tokens"]), CFG.vocab)
+        assert list(np.argmax(r["logits"], axis=1)) == r["tokens"]
+        seq = list(req.prompt) + r["tokens"][:-1]
+        ref = dense_forward(params, CFG, jnp.asarray([seq]))[0]
+        P = len(req.prompt)
+        np.testing.assert_allclose(r["logits"], np.asarray(ref[P - 1:]),
+                                   rtol=1e-5, atol=1e-6)
