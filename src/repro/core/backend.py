@@ -75,16 +75,22 @@ class Backend:
         return ss
 
     def scope(self, name: str):
+        """Push ``name`` onto the scope path, and onto JAX's name stack
+        (``jax.named_scope``), so every op traced inside it carries the
+        scope in its compiled ``op_name`` metadata."""
         ops = self
 
         class _Scope:
             def __enter__(self):
+                self._named = jax.named_scope(name)
+                self._named.__enter__()
                 ops.scope_path.append(name)
                 ops._scope_changed()
 
             def __exit__(self, *exc):
                 ops.scope_path.pop()
                 ops._scope_changed()
+                return self._named.__exit__(*exc)
 
         return _Scope()
 
@@ -313,6 +319,8 @@ class JOps(Backend):
         return jax.lax.with_sharding_constraint(a, NamedSharding(mesh, spec))
 
     def layer_loop(self, fn, stacked_params, x, n_layers: int, aux=None):
+        # one traced body serves every layer: it runs under the stacked
+        # wildcard scope, which the per-scope maps key as layer*/...
         def body(carry, xs):
             p, i, a = xs
             new_x, aux_out = fn(p, carry, i, a)
@@ -320,7 +328,8 @@ class JOps(Backend):
             return new_x, aux_out
 
         idx = jnp.arange(n_layers)
-        out, aux_outs = jax.lax.scan(body, x, (stacked_params, idx, aux))
+        with self.scope(STACK_SCOPE):
+            out, aux_outs = jax.lax.scan(body, x, (stacked_params, idx, aux))
         return out, aux_outs
 
     def ssm_scan(self, decay, drive, n_steps: int, time_axis: int = 1):

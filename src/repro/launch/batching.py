@@ -112,7 +112,8 @@ class ContinuousBatchingEngine:
     ``params`` may live on host; with a mesh they are placed under the
     bitwise-safe column-parallel serving sharding. ``registry`` (a
     :class:`repro.obs.MetricsRegistry`) receives occupancy / queue-depth
-    gauges and per-lane ``serve.decode_latency_s{lane=N}`` histograms.
+    gauges, request counters and the ``serve.decode_latency_s`` histogram
+    of decode steps. :meth:`step` opens ``engine.*`` spans.
     With ``keep_logits`` every response also carries ``"logits"``, an f32
     ``[n_tokens, vocab]`` host array: row t is the distribution token t
     was taken from (row 0 from the prefill, the rest from decode steps) —
@@ -145,6 +146,7 @@ class ContinuousBatchingEngine:
         self.lanes: List[Optional[_Lane]] = [None] * n_lanes
         self.responses: List[Dict[str, Any]] = []
         self.steps = 0
+        self.admitted = 0
         self.decode_tokens = 0
         self.decode_s = 0.0
 
@@ -246,6 +248,7 @@ class ContinuousBatchingEngine:
         self.registry.gauge("serve.kv_pages_free", self.free_pages)
 
     def _admit(self):
+        rec = obs.recording()
         while self.queue:
             free = [i for i, l in enumerate(self.lanes) if l is None]
             if not free:
@@ -260,18 +263,29 @@ class ContinuousBatchingEngine:
             # pad the prompt to whole pages: one prefill compilation per
             # page-count bucket, and the cache slice lands page-aligned
             toks = page_padded(req.prompt, self.page_size, self.max_seq)
-            tok, row, sl = self._prefill(self.params, jnp.asarray(toks),
-                                         jnp.asarray(P, jnp.int32))
-            self.cache = self._insert(self.cache, sl,
-                                      jnp.asarray(lane, jnp.int32))
-            first = int(tok)
-            self.free_pages -= pages
-            self.lanes[lane] = _Lane(req=req, length=P, pages=pages,
-                                     out=[first], t_admit=time.perf_counter())
-            if self.keep_logits:
-                self.lanes[lane].logits.append(np.asarray(row))
-            self._count("serve.requests_admitted")
-            self._finish_if_done(lane, first)
+            attrs = (dict(n=1, rid=req.rid, lane=lane, tokens=P,
+                          padded=toks.shape[1]) if rec else {})
+            with obs.span("engine.admit", **attrs):
+                with obs.span("engine.prefill"):
+                    tok, row, sl = self._prefill(self.params,
+                                                 jnp.asarray(toks),
+                                                 jnp.asarray(P, jnp.int32))
+                with obs.span("engine.insert"):
+                    self.cache = self._insert(self.cache, sl,
+                                              jnp.asarray(lane, jnp.int32))
+                with obs.span("engine.first_token"):
+                    first = int(tok)
+                    t_admit = time.perf_counter()
+                    if self.keep_logits:
+                        row = np.asarray(row)
+                self.free_pages -= pages
+                self.lanes[lane] = _Lane(req=req, length=P, pages=pages,
+                                         out=[first], t_admit=t_admit)
+                if self.keep_logits:
+                    self.lanes[lane].logits.append(row)
+                self.admitted += 1
+                self._count("serve.requests_admitted")
+                self._finish_if_done(lane, first)
 
     def _finish_if_done(self, i: int, last_tok: int):
         lane = self.lanes[i]
@@ -295,42 +309,62 @@ class ContinuousBatchingEngine:
         self._count("serve.requests_completed")
 
     def step(self) -> bool:
-        """Admit + one decode step for every active lane. False = idle."""
-        self._admit()
-        self._gauges()
-        active = [i for i, l in enumerate(self.lanes) if l is not None]
-        if not active:
-            return bool(self.queue)
-        tokens = np.zeros((self.n_lanes,), np.int32)
-        offsets = np.zeros((self.n_lanes,), np.int32)
-        for i, lane in enumerate(self.lanes):
-            if lane is not None:
-                tokens[i] = lane.out[-1]
-                offsets[i] = lane.length
-        t0 = time.perf_counter()
-        nxt, rows, self.cache = self._decode(self.params, self.cache,
-                                             jnp.asarray(tokens),
-                                             jnp.asarray(offsets))
-        nxt = jax.block_until_ready(nxt)
-        dt = time.perf_counter() - t0
-        self.steps += 1
-        self.decode_tokens += len(active)
-        self.decode_s += dt
-        if self.registry is not None:
-            self.registry.observe("serve.decode_latency_s", dt)
-            for i in active:
-                self.registry.observe(f"serve.decode_latency_s{{lane={i}}}",
-                                      dt)
-            self._count("serve.tokens", len(active))
-        nxt = np.asarray(nxt)
-        rows = None if rows is None else np.asarray(rows)
-        for i in active:
-            lane = self.lanes[i]
-            lane.length += 1
-            lane.out.append(int(nxt[i]))
-            if rows is not None:
-                lane.logits.append(rows[i])
-            self._finish_if_done(i, int(nxt[i]))
+        """Admit + one decode step for every active lane. False = idle.
+
+        Spans (``obs.span``, recorded only under a tracer or the JAX
+        profiler): ``engine.step`` (attrs ``queue``, ``admitted``) holds
+        one ``engine.admit`` per admission (⊃ ``engine.prefill``,
+        ``engine.insert``, ``engine.first_token``), then
+        ``engine.schedule``, ``engine.decode`` (attrs ``lanes``, ``kv``:
+        the positions the active lanes attend), ``engine.decode_wait``,
+        ``engine.readback`` and ``engine.bookkeep``."""
+        rec = obs.recording()
+        with obs.span("engine.step") as step_span:
+            before = self.admitted
+            self._admit()
+            with obs.span("engine.schedule"):
+                self._gauges()
+                active = [i for i, l in enumerate(self.lanes)
+                          if l is not None]
+                if active:
+                    tokens = np.zeros((self.n_lanes,), np.int32)
+                    offsets = np.zeros((self.n_lanes,), np.int32)
+                    for i in active:
+                        tokens[i] = self.lanes[i].out[-1]
+                        offsets[i] = self.lanes[i].length
+                    t0 = time.perf_counter()
+                    tokens, offsets = jnp.asarray(tokens), jnp.asarray(offsets)
+            if rec:
+                step_span.set(queue=len(self.queue),
+                              admitted=self.admitted - before)
+            if not active:
+                return bool(self.queue)
+            attrs = (dict(lanes=len(active),
+                          kv=sum(self.lanes[i].length + 1 for i in active))
+                     if rec else {})
+            with obs.span("engine.decode", **attrs):
+                nxt, rows, self.cache = self._decode(self.params, self.cache,
+                                                     tokens, offsets)
+            with obs.span("engine.decode_wait"):
+                nxt = jax.block_until_ready(nxt)
+            dt = time.perf_counter() - t0
+            with obs.span("engine.readback"):
+                nxt = np.asarray(nxt)
+                rows = None if rows is None else np.asarray(rows)
+            with obs.span("engine.bookkeep"):
+                self.steps += 1
+                self.decode_tokens += len(active)
+                self.decode_s += dt
+                if self.registry is not None:
+                    self.registry.observe("serve.decode_latency_s", dt)
+                    self._count("serve.tokens", len(active))
+                for i in active:
+                    lane = self.lanes[i]
+                    lane.length += 1
+                    lane.out.append(int(nxt[i]))
+                    if rows is not None:
+                        lane.logits.append(rows[i])
+                    self._finish_if_done(i, int(nxt[i]))
         return True
 
     def run(self, requests: Sequence[Request] = (),

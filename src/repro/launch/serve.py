@@ -123,14 +123,13 @@ class QuantJOps(JOps):
 
     def layer_loop(self, fn, stacked_params, x, n_layers: int, aux=None):
         # one traced body serves every layer, so monitor observations from
-        # inside the scan carry the stacked wildcard scope (matching the
-        # certificate's layer* / layer<i> envelope keys), not an empty path.
-        # The span measures TRACE time of the scanned quantize/matmul body
-        # (once per compile) — the per-scope attribution of compile cost
-        from repro.core.scopes import STACK_SCOPE
-        with self.scope(STACK_SCOPE), obs.span(
-                "serve.layer_scan", backend=type(self).__name__,
-                layers=n_layers):
+        # inside the scan carry the stacked wildcard scope JOps.layer_loop
+        # pushes (matching the certificate's layer* / layer<i> envelope
+        # keys), not an empty path. The span measures TRACE time of the
+        # scanned quantize/matmul body (once per compile) — the per-scope
+        # attribution of compile cost
+        with obs.span("serve.layer_scan", backend=type(self).__name__,
+                      layers=n_layers):
             return super().layer_loop(fn, stacked_params, x, n_layers, aux)
 
 
@@ -178,7 +177,8 @@ class _SuffixLanes:
             self._refresh_dyn()
 
     def _lane_loop(self, fn, stacked_params, x, n_layers, aux, super_loop):
-        from repro.core.scopes import STACK_SCOPE
+        # super_loop (JOps.layer_loop) pushes the one layer* scope level
+        # that the suffix lanes look past
         outer = list(self.scope_path)
         self._stack_ctx = (outer, n_layers)
         self._lane_cache = {}
@@ -193,9 +193,8 @@ class _SuffixLanes:
                 self._dyn = None
 
         try:
-            with self.scope(STACK_SCOPE), obs.span(
-                    "serve.layer_scan", backend=type(self).__name__,
-                    layers=n_layers):
+            with obs.span("serve.layer_scan", backend=type(self).__name__,
+                          layers=n_layers):
                 return super_loop(scoped_fn, stacked_params, x,
                                   n_layers, aux)
         finally:

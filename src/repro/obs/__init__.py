@@ -8,7 +8,9 @@ system that produces and serves them *observable*, in four pieces:
   ``obs.gauge(...)`` are module-level no-ops until a CLI installs a tracer
   (``--trace out.jsonl`` on ``python -m repro.certify``), after which one
   certify run yields a per-stage timing + ladder-compile-count + store
-  hit/miss profile.
+  hit/miss profile. While the JAX profiler collects, spans are also
+  profiler host events on the device trace's clock (the serving engine's
+  ``engine.*`` spans); ``obs.recording()`` says whether a span is kept.
 * :mod:`repro.obs.metrics` — serving-side latency histograms
   (prefill/decode split), tokens/s and occupancy gauges, exported as JSONL
   and as a Prometheus text exposition (no server dependency).
@@ -41,6 +43,7 @@ from .trace import (  # noqa: F401
     gauge,
     get_tracer,
     load_events,
+    recording,
     shutdown,
     span,
     validate_events,

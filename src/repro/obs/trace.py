@@ -24,6 +24,15 @@ reconstruct interleavings without trusting the clock. The global tracer is
 disabled by default: every obs call is then a cheap no-op, so instrumented
 library code (the certify pipeline, the store, the serving path) pays
 nothing unless a CLI opted in via :func:`configure`.
+
+Spans have a second sink, the JAX profiler: while it collects
+(``jax.profiler.start_trace`` … ``stop_trace``), every span is also a
+profiler host event of the same name on the calling thread's line, with
+its attributes as the event's stats, so program spans share the device
+trace's clock. With no tracer configured and the profiler off,
+:func:`span` returns the null span after one check (``TraceMe.is_enabled``,
+tens of ns). Instrumented code that computes costly attributes checks
+:func:`recording` first.
 """
 from __future__ import annotations
 
@@ -37,6 +46,30 @@ from typing import Any, Dict, Iterable, List, Optional
 SCHEMA = 1
 
 _EVENT_TYPES = ("meta", "span", "event", "counters", "gauges")
+
+
+_TraceAnnotation: Any = None     # jax.profiler.TraceAnnotation
+
+
+def _first_profiling() -> bool:
+    # jax.profiler is imported on the first span, never at module load
+    global _profiling, _TraceAnnotation
+    from jax.profiler import TraceAnnotation
+    _TraceAnnotation = TraceAnnotation
+    _profiling = TraceAnnotation.is_enabled
+    return _profiling()
+
+
+_profiling = _first_profiling     # TraceMe.is_enabled once resolved
+
+
+def _annotation(name: str, attrs: Dict[str, Any]):
+    """An entered profiler host event, or None while the profiler is off."""
+    if not _profiling():
+        return None
+    ann = _TraceAnnotation(name, **attrs)
+    ann.__enter__()
+    return ann
 
 
 class _NullSpan:
@@ -60,16 +93,50 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
-class _Span:
-    """An open span; writes its line on ``__exit__``."""
+class _ProfilerSpan:
+    """A span with no tracer configured, recorded while the JAX profiler
+    collects: a host event on the profiler's clock, attributes as stats."""
 
-    __slots__ = ("_tracer", "name", "attrs", "_t0", "_wall", "_depth",
-                 "_parent")
+    __slots__ = ("name", "attrs", "_ann")
 
-    def __init__(self, tracer: "Tracer", name: str, attrs: Dict[str, Any]):
-        self._tracer = tracer
+    def __init__(self, name: str, attrs: Dict[str, Any]):
         self.name = name
         self.attrs = attrs
+        self._ann = None
+
+    def __enter__(self):
+        self._ann = _annotation(self.name, self.attrs)
+        return self
+
+    def set(self, **attrs):
+        """Attach attributes discovered mid-span (e.g. a search's result)."""
+        self.attrs.update(attrs)
+        if self._ann is not None:
+            self._ann.set_metadata(**attrs)
+        return self
+
+    def rename(self, name: str):
+        """Change the span's name before it closes (e.g. a probe that
+        turned out to be the one paying the compile). A profiler event
+        keeps the name it opened with."""
+        self.name = str(name)
+        return self
+
+    def __exit__(self, *exc):
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        return False
+
+
+class _Span(_ProfilerSpan):
+    """An open span of a :class:`Tracer`; writes its line on ``__exit__``
+    (and is a profiler event too while the profiler collects)."""
+
+    __slots__ = ("_tracer", "_t0", "_wall", "_depth", "_parent")
+
+    def __init__(self, tracer: "Tracer", name: str, attrs: Dict[str, Any]):
+        super().__init__(name, attrs)
+        self._tracer = tracer
 
     def __enter__(self):
         tr = self._tracer
@@ -78,23 +145,14 @@ class _Span:
             self._depth = len(stack)
             self._parent = stack[-1].name if stack else None
             stack.append(self)
+        self._ann = _annotation(self.name, self.attrs)
         self._wall = time.time()
         self._t0 = time.perf_counter()
         return self
 
-    def set(self, **attrs):
-        """Attach attributes discovered mid-span (e.g. a search's result)."""
-        self.attrs.update(attrs)
-        return self
-
-    def rename(self, name: str):
-        """Change the span's name before it closes (e.g. a probe that
-        turned out to be the one paying the compile)."""
-        self.name = str(name)
-        return self
-
     def __exit__(self, *exc):
         dur = time.perf_counter() - self._t0
+        super().__exit__(*exc)
         tr = self._tracer
         with tr._lock:
             if tr._stack and tr._stack[-1] is self:
@@ -206,14 +264,25 @@ def get_tracer() -> Optional[Tracer]:
 
 
 def enabled() -> bool:
+    """Whether a tracer is configured (the JSONL sink, not the profiler)."""
     return _TRACER is not None
 
 
+def recording() -> bool:
+    """Whether a span opened now is recorded by either sink: a tracer is
+    configured or the JAX profiler is collecting. Check it before computing
+    costly span attributes."""
+    return _TRACER is not None or _profiling()
+
+
 def span(name: str, **attrs):
-    """Open a span on the global tracer; a no-op context when disabled."""
-    if _TRACER is None:
-        return _NULL_SPAN
-    return _TRACER.span(name, **attrs)
+    """Open a span on the global tracer and, while the JAX profiler
+    collects, as a profiler host event; a no-op context when neither."""
+    if _TRACER is not None:
+        return _TRACER.span(name, **attrs)
+    if _profiling():
+        return _ProfilerSpan(str(name), attrs)
+    return _NULL_SPAN
 
 
 def event(name: str, **fields):

@@ -86,6 +86,77 @@ def test_disabled_obs_calls_are_noops():
     assert obs.get_tracer() is None
 
 
+def test_span_is_the_null_span_with_no_tracer_and_the_profiler_off():
+    from repro.obs import trace
+    assert not obs.recording()
+    assert obs.span("engine.step", a=1) is trace._NULL_SPAN
+    with obs.span("engine.step") as sp:
+        sp.set(queue=3)
+    assert obs.get_tracer() is None
+
+
+def _profiled(tmp_path, fn):
+    """Host events named ``t.*`` recorded while ``fn`` runs under the JAX
+    profiler: [(name, stats, start_ns, end_ns)]."""
+    import glob
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    return [(ev.name, dict(ev.stats), ev.start_ns,
+             ev.start_ns + ev.duration_ns)
+            for plane in ProfileData.from_file(path[0]).planes
+            for line in plane.lines for ev in line.events
+            if ev.name.startswith("t.")]
+
+
+def test_spans_are_profiler_events_while_it_collects(tmp_path):
+    def run():
+        assert obs.recording() and not obs.enabled()
+        with obs.span("t.outer", rid=7, n=1) as sp:
+            sp.set(tokens=300)
+            sp.rename("t.renamed")      # the profiler keeps the first name
+            with obs.span("t.inner"):
+                jnp.ones(4).block_until_ready()
+
+    ev = {n: (st, s, e) for n, st, s, e in _profiled(tmp_path, run)}
+    assert set(ev) == {"t.outer", "t.inner"}
+    assert ev["t.outer"][0] == {"rid": 7, "n": 1, "tokens": 300}
+    (_, s0, e0), (_, s1, e1) = ev["t.outer"], ev["t.inner"]
+    assert s0 <= s1 <= e1 <= e0
+    assert not obs.recording()
+
+
+def test_tracer_spans_also_reach_the_profiler(tmp_path):
+    tr = obs.configure()
+
+    def run():
+        with obs.span("t.both", k=2) as sp:
+            sp.set(hit=1)
+
+    ev = _profiled(tmp_path, run)
+    assert [(n, st) for n, st, _, _ in ev] == [("t.both", {"k": 2,
+                                                           "hit": 1})]
+    (sp_ev,) = [e for e in tr.events if e["type"] == "span"]
+    assert sp_ev["name"] == "t.both" and sp_ev["attrs"] == {"k": 2, "hit": 1}
+
+
+def test_obs_trace_does_not_import_jax_at_load():
+    import subprocess
+    import sys
+    path = os.path.join(os.path.dirname(obs.__file__), "trace.py")
+    code = ("import importlib.util, sys\n"
+            f"spec = importlib.util.spec_from_file_location('t', {path!r})\n"
+            "m = importlib.util.module_from_spec(spec)\n"
+            "spec.loader.exec_module(m)\n"
+            "assert 'jax' not in sys.modules, 'jax imported at load'\n"
+            "assert m.span('x') is m._NULL_SPAN\n")
+    subprocess.run([sys.executable, "-c", code], check=True)
+
+
 # ---------------------------------------------------------------------------
 # JSONL schema round-trip
 # ---------------------------------------------------------------------------

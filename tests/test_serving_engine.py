@@ -178,13 +178,14 @@ def test_gauges_and_per_lane_histograms(params):
     assert reg.gauges["serve.admission_queue_depth"] == 0.0
     eng.run([])
     assert reg.gauges["serve.batch_occupancy"] == 0.0
-    for lane in (0, 1):
-        h = reg.histograms[f"serve.decode_latency_s{{lane={lane}}}"]
-        assert h.count >= 1
+    # one observation per decode step, not one per lane
+    assert reg.histograms["serve.decode_latency_s"].count == eng.steps >= 2
+    assert not [k for k in reg.histograms if "lane=" in k]
     assert reg.counters["serve.requests_completed"] == 2
-    # the lane label renders as a proper Prometheus label
+    # a labelled series renders with a proper Prometheus label
+    assert not eng.submit(Request(rid=9, prompt=[1] * 40, max_new_tokens=1))
     prom = reg.render_prometheus()
-    assert 'serve_decode_latency_s_bucket{lane="0",le=' in prom
+    assert 'serve_requests_rejected{reason="too_long"} 1' in prom
 
 
 def test_responses_carry_certificate_bars(params):
@@ -287,3 +288,113 @@ def test_kept_logits_match_plain_reference(params):
         P = len(req.prompt)
         np.testing.assert_allclose(r["logits"], np.asarray(ref[P - 1:]),
                                    rtol=1e-5, atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# engine spans and the model's named scopes
+# ---------------------------------------------------------------------------
+
+ENGINE_SPANS = {"engine.step", "engine.admit", "engine.prefill",
+                "engine.insert", "engine.first_token", "engine.schedule",
+                "engine.decode", "engine.decode_wait", "engine.readback",
+                "engine.bookkeep"}
+
+
+def _served(params, traced: bool):
+    sc = serve.ServeConfig(arch="qwen2_7b", batch=2, max_seq=48)
+    eng = ContinuousBatchingEngine(CFG, sc, params, n_lanes=2, max_seq=48,
+                                   page_size=8, queue_depth=8,
+                                   keep_logits=True)
+    tracer = obs.configure() if traced else None
+    try:
+        responses = eng.run(_requests(3, seed=4, max_new=4))
+    finally:
+        obs.shutdown()
+    return responses, tracer
+
+
+def test_engine_spans_nest_with_their_attributes(params):
+    responses, tracer = _served(params, traced=True)
+    spans = [e for e in tracer.events if e["type"] == "span"]
+    by = {}
+    for e in spans:
+        by.setdefault(e["name"], []).append(e)
+    assert set(by) == ENGINE_SPANS
+    assert all(e["parent"] == "engine.step" for e in by["engine.admit"])
+    for child in ("engine.prefill", "engine.insert", "engine.first_token"):
+        assert all(e["parent"] == "engine.admit" for e in by[child])
+        assert len(by[child]) == len(by["engine.admit"]) == 3
+    for name in ("engine.schedule", "engine.decode", "engine.decode_wait",
+                 "engine.readback", "engine.bookkeep"):
+        assert all(e["parent"] == "engine.step" for e in by[name])
+    assert sorted(e["attrs"]["rid"] for e in by["engine.admit"]) == [0, 1, 2]
+    for e in by["engine.admit"]:
+        a = e["attrs"]
+        assert a["n"] == 1 and a["lane"] in (0, 1)
+        assert 5 <= a["tokens"] <= 12 and a["padded"] == 8 * -(
+            -a["tokens"] // 8)
+    assert sum(e["attrs"]["admitted"] for e in by["engine.step"]) == 3
+    assert all("queue" in e["attrs"] for e in by["engine.step"])
+    # decode attrs: active lanes, and the positions they attend
+    first = by["engine.decode"][0]["attrs"]
+    assert 1 <= first["lanes"] <= 2 and first["kv"] >= first["lanes"]
+    assert (sum(e["attrs"]["lanes"] for e in by["engine.decode"])
+            == sum(len(r["tokens"]) - 1 for r in responses))
+
+
+def test_engine_output_identical_with_tracing_on_and_off(params):
+    off, _ = _served(params, traced=False)
+    on, _ = _served(params, traced=True)
+    assert [r["id"] for r in on] == [r["id"] for r in off]
+    for a, b in zip(on, off):
+        assert a["tokens"] == b["tokens"]
+        np.testing.assert_array_equal(a["logits"], b["logits"])
+
+
+def test_engine_spans_reach_the_profiler_with_stats(params, tmp_path):
+    import glob
+    from jax.profiler import ProfileData
+    sc = serve.ServeConfig(arch="qwen2_7b", batch=2, max_seq=48)
+    eng = ContinuousBatchingEngine(CFG, sc, params, n_lanes=2, max_seq=48,
+                                   page_size=8, queue_depth=8)
+    eng.run(_requests(1, seed=5, max_new=2))          # compile outside
+    assert not obs.recording()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        assert obs.recording() and not obs.enabled()
+        eng.run(_requests(2, seed=5, max_new=3))
+    finally:
+        jax.profiler.stop_trace()
+    assert not obs.recording()
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    events = [ev for plane in ProfileData.from_file(path[0]).planes
+              for line in plane.lines for ev in line.events
+              if ev.name.startswith("engine.")]
+    assert {ev.name for ev in events} == ENGINE_SPANS
+    admits = [dict(ev.stats) for ev in events if ev.name == "engine.admit"]
+    assert sorted(a["rid"] for a in admits) == [0, 1]
+    assert all(a["n"] == 1 and a["padded"] % 8 == 0 for a in admits)
+    dec = [dict(ev.stats) for ev in events if ev.name == "engine.decode"]
+    assert all(d["lanes"] >= 1 and d["kv"] >= d["lanes"] for d in dec)
+
+
+@pytest.mark.parametrize("fmt", [None, {"": {"k": 11, "emax": 15,
+                                             "emin": -14}}])
+def test_compiled_decode_names_the_model_scopes(params, fmt):
+    import re
+    sc = serve.ServeConfig(arch="qwen2_7b", batch=2, max_seq=32,
+                           precision_layer_format=fmt)
+    eng = ContinuousBatchingEngine(CFG, sc, params, n_lanes=2, max_seq=32,
+                                   page_size=8)
+    lanes = jnp.zeros((2,), jnp.int32)
+    text = eng._decode.lower(eng.params, eng.cache, lanes,
+                             lanes).compile().as_text()
+    paths = set(re.findall(r'op_name="([^"]*)"', text))
+    segs = [p.split("/") for p in paths]
+    for scope in ("embed", "attn", "mlp", "head"):
+        assert any(scope in s for s in segs), scope
+    # sub-layer scopes sit inside the scanned layer body, under layer*
+    for scope in ("attn", "mlp"):
+        assert any(s[s.index("layer*"):s.index("layer*") + 3]
+                   == ["layer*", "while", "body"]
+                   for s in segs if scope in s and "layer*" in s), scope
