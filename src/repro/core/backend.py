@@ -164,13 +164,17 @@ class Backend:
     # structure
     def layer_loop(self, fn: Callable, stacked_params, x, n_layers: int,
                    aux=None):
-        """Apply ``fn(layer_params, x, layer_index, aux_i) -> (x, aux_out_i)``
-        across layers. Returns (x, stacked_aux_out).
+        """Apply ``fn(layer_params, x, layer_index, aux) -> (x, aux)``
+        across layers. Returns (x, aux).
 
-        JOps uses lax.scan over stacked parameters (O(1) HLO in depth —
-        essential for 512-device compiles of 56-layer models); CaaOps
-        unrolls in Python so per-layer trace records survive. ``aux`` is an
-        optional per-layer pytree (e.g. the layer's KV cache slice)."""
+        ``aux`` is an optional pytree of stacked ``[L, ...]`` state (the
+        decode cache), handed whole from layer to layer: layer ``i`` reads
+        and writes only its own slice at index ``i``, so a scanned loop
+        keeps it in the carry and XLA updates it in place, with no
+        per-layer copy in or out. JOps uses lax.scan over stacked
+        parameters (O(1) HLO in depth — essential for 512-device compiles
+        of 56-layer models); CaaOps unrolls in Python (a static ``i``) so
+        per-layer trace records survive."""
         raise NotImplementedError
 
     def ssm_scan(self, decay, drive, n_steps: int, time_axis: int = 1):
@@ -322,15 +326,16 @@ class JOps(Backend):
         # one traced body serves every layer: it runs under the stacked
         # wildcard scope, which the per-scope maps key as layer*/...
         def body(carry, xs):
-            p, i, a = xs
-            new_x, aux_out = fn(p, carry, i, a)
-            new_x = self.shard_hint(new_x, "act_batch")
-            return new_x, aux_out
+            h, a = carry
+            p, i = xs
+            h, a = fn(p, h, i, a)
+            return (self.shard_hint(h, "act_batch"), a), None
 
         idx = jnp.arange(n_layers)
         with self.scope(STACK_SCOPE):
-            out, aux_outs = jax.lax.scan(body, x, (stacked_params, idx, aux))
-        return out, aux_outs
+            (out, aux), _ = jax.lax.scan(body, (x, aux),
+                                         (stacked_params, idx))
+        return out, aux
 
     def ssm_scan(self, decay, drive, n_steps: int, time_axis: int = 1):
         dec = jnp.moveaxis(decay, time_axis, 0)
@@ -361,23 +366,11 @@ class UnrolledLayerLoop:
     bit-for-bit checked against the latter."""
 
     def layer_loop(self, fn, stacked_params, x, n_layers: int, aux=None):
-        aux_outs = []
         for i in range(n_layers):
             layer_params = jax.tree_util.tree_map(lambda p: p[i], stacked_params)
-            aux_i = (
-                None if aux is None
-                else jax.tree_util.tree_map(lambda a: a[i], aux)
-            )
             with self.scope(f"layer{i}"):
-                x, aux_out = fn(layer_params, x, i, aux_i)
-            aux_outs.append(aux_out)
-        if all(a is None for a in aux_outs):
-            stacked = None
-        else:
-            stacked = jax.tree_util.tree_map(
-                lambda *xs: jnp.stack(xs), *aux_outs
-            )
-        return x, stacked
+                x, aux = fn(layer_params, x, i, aux)
+        return x, aux
 
 
 class CaaOps(UnrolledLayerLoop, Backend):
@@ -809,8 +802,8 @@ class StackedCaaOps(CaaOps):
         self._lane_cache = {}
 
         def body(carry, xs):
-            p, i, a = xs
-            cx, state = carry
+            p, i = xs
+            cx, state, a = carry
             self._in_stack = True
             self._layer_index = i
             self._set_stack_state(state)
@@ -819,22 +812,22 @@ class StackedCaaOps(CaaOps):
             # sub-layer scope pushes inside fn re-pin to their suffix lane
             # via _scope_changed → _apply_stack_lane
             self._apply_stack_lane()
-            new_x, aux_out = fn(p, cx, i, a)
+            new_x, a = fn(p, cx, i, a)
             new_x = _canon_caa(new_x)
             stats = (jnp.max(new_x.dbar), jnp.max(new_x.ebar))
-            return (new_x, self._get_stack_state()), (aux_out, stats)
+            return (new_x, self._get_stack_state(), a), stats
 
         idx = jnp.arange(n_layers)
         with self.scope(STACK_SCOPE):
-            (out, state), (aux_outs, stats) = jax.lax.scan(
-                body, (_canon_caa(x), self._stack_state_init(n_layers)),
-                (stacked_params, idx, aux))
+            (out, state, aux), stats = jax.lax.scan(
+                body, (_canon_caa(x), self._stack_state_init(n_layers), aux),
+                (stacked_params, idx))
             self._in_stack = False
             self._layer_index = None
             self._stack_ctx = None
             self._finish_stack_state(state)
         self.layer_stats = {"abs_u": stats[0], "rel_u": stats[1]}
-        return out, aux_outs
+        return out, aux
 
 
 class StackedRangeCaaOps(StackedCaaOps):
@@ -1614,23 +1607,23 @@ class StackedAffineRangeCaaOps(AffineRangeCaaOps):
         ctr0 = jnp.asarray(self._sym_counter, jnp.int32)
 
         def body(carry, xs):
-            p, i, a = xs
-            cf, clo, chi, acc, ctr = carry
+            p, i = xs
+            cf, clo, chi, acc, ctr, a = carry
             self._in_stack = True
             self._layer_index = i
             self._lane_acc = acc
             self._sym_ctr_traced = ctr
             cx = AffTensor(cf, iv.Interval(clo, chi))
-            new_x, aux_out = fn(p, cx, i, a)
+            new_x, a = fn(p, cx, i, a)
             nt = _canon_aff(self._lift(new_x, observe=False))
             return ((nt.form, nt.ivl.lo, nt.ivl.hi,
-                     self._lane_acc, self._sym_ctr_traced), aux_out)
+                     self._lane_acc, self._sym_ctr_traced, a), None)
 
         idx = jnp.arange(n_layers)
         with self.scope(STACK_SCOPE):
-            carry0 = (x0.form, x0.ivl.lo, x0.ivl.hi, acc0, ctr0)
-            (out_f, out_lo, out_hi, acc, ctr), aux_outs = jax.lax.scan(
-                body, carry0, (stacked_params, idx, aux))
+            carry0 = (x0.form, x0.ivl.lo, x0.ivl.hi, acc0, ctr0, aux)
+            (out_f, out_lo, out_hi, acc, ctr, aux), _ = jax.lax.scan(
+                body, carry0, (stacked_params, idx))
             self._in_stack = False
             self._layer_index = None
             self._stack_ctx = None
@@ -1638,7 +1631,7 @@ class StackedAffineRangeCaaOps(AffineRangeCaaOps):
         self._done_lanes.append(acc)
         # eager ids must stay ahead of every id the scan consumed
         self._sym_counter = int(ctr)
-        return AffTensor(out_f, iv.Interval(out_lo, out_hi)), aux_outs
+        return AffTensor(out_f, iv.Interval(out_lo, out_hi)), aux
 
     def collect_ranges(self) -> Dict[str, RangeStat]:
         """Concretised lanes (``layer{i}`` / ``layer{i}/{sub}`` keys)

@@ -13,7 +13,7 @@ that requests join and leave independently:
   not at token 37. Over-capacity submissions are rejected outright.
 - **Batched prefill-insert**: a new request prefills at batch 1 (padded to
   a whole number of pages) and its cache slice + per-lane index are
-  inserted into the running [L, B, Smax, ...] cache at the free lane —
+  inserted into the running [L, B, ...] cache at the free lane —
   the decode batch never drains to let someone in.
 - **Lane recycling**: on EOS / max-new-tokens the lane's pages return to
   the pool and the lane is immediately reusable; stale cache contents need
@@ -26,8 +26,8 @@ request ALONE through the single-device eager reference
 1, the same page-padded prefill shape, no mesh). This holds because every
 per-lane row of the transformer is bitwise independent of batch
 composition — f32 matmul rows don't see other rows, masked-softmax columns
-beyond a lane's length contribute exact zeros, cache writes are vmapped
-per lane — which the engine tests assert against staggered-arrival
+beyond a lane's length contribute exact zeros, cache writes touch each
+lane's own window only — which the engine tests assert against staggered-arrival
 schedules. Prefill is compared at like shapes: a GEMM's rows are not
 bitwise independent of its row COUNT (XLA:CPU blocks a 6-row and a 16-row
 product differently), so the reference pads exactly as the engine does.

@@ -93,20 +93,19 @@ class RematJOps(JOps):
         return a
 
     def layer_loop(self, fn, stacked_params, x, n_layers: int, aux=None):
-        def fn_constrained(p, carry, i, a):
-            new_x, aux_out = fn(p, carry, i, a)
-            return self._residual_constraint(new_x), aux_out
+        def fn_constrained(p, h, i, a):
+            h, a = fn(p, h, i, a)
+            return self._residual_constraint(h), a
 
         fn_r = jax.checkpoint(fn_constrained, static_argnums=())
 
         def body(carry, xs):
-            p, i, a = xs
-            new_x, aux_out = fn_r(p, carry, i, a)
-            return new_x, aux_out
+            (h, a), (p, i) = carry, xs
+            return fn_r(p, h, i, a), None
         idx = jnp.arange(n_layers)
         x = self._residual_constraint(x)
-        out, aux_outs = jax.lax.scan(body, x, (stacked_params, idx, aux))
-        return out, aux_outs
+        (out, aux), _ = jax.lax.scan(body, (x, aux), (stacked_params, idx))
+        return out, aux
 
 
 def _backend(tc: TrainConfig, remat: Optional[bool] = None, mesh=None):

@@ -7,7 +7,7 @@ families. Softmax here is *the* paper object: its abs→rel error conversion
 """
 from __future__ import annotations
 
-from typing import NamedTuple, Optional
+from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -16,27 +16,54 @@ from . import layers as L
 
 
 class KVCache(NamedTuple):
-    k: jax.Array       # [B, Smax, K, Dh]  (MLA: compressed c_kv [B, Smax, R])
-    v: jax.Array       # [B, Smax, K, Dh]  (MLA: rope key     [B, Smax, Dr])
-    index: jax.Array   # int32 tokens already present: scalar, or [B] when
-    #                    lanes advance independently (continuous batching)
+    """One layer's view of the stacked decode cache: the buffers stay whole
+    ([L, ...]) so a scanned layer loop can write them in place in its
+    carry; ``layer`` picks the slice this attention writes and reads.
+    Positions run along each buffer's second-to-last axis."""
+    k: jax.Array       # [L, B, K, Smax, Dh]  (MLA: compressed c_kv [L, B, Smax, R])
+    v: jax.Array       # [L, B, K, Smax, Dh]  (MLA: rope key     [L, B, Smax, Dr])
+    index: jax.Array   # int32 tokens already present in this layer: scalar,
+    #                    or [B] when lanes advance independently
+    layer: Any         # the layer index: a Python int or a traced scalar
 
 
-def _cache_write(buf, upd, index):
-    """Append ``upd`` into ``buf`` at sequence offset ``index`` (dim 1 of
-    [B, Smax, ...]). A scalar index writes the whole batch at one offset
-    (the classic lock-step decode); a [B] vector writes each lane at its
-    own offset (continuous batching) via a vmapped per-lane update."""
+def layer_of(stacked, layer):
+    """Layer ``layer`` (a Python int or a traced index) of a stacked
+    ``[L, ...]`` cache leaf."""
+    return jax.lax.dynamic_index_in_dim(stacked, layer, 0, keepdims=False)
+
+
+def with_layer(stacked, layer, value):
+    """``stacked`` with layer ``layer`` replaced by ``value`` (in place
+    where ``stacked`` is a scan carry)."""
+    return jax.lax.dynamic_update_index_in_dim(
+        stacked, value.astype(stacked.dtype), layer, 0)
+
+
+def _cache_write(buf, upd, layer, index):
+    """Write ``upd`` into layer ``layer`` of the stacked ``buf`` at
+    position ``index`` of its second-to-last (sequence) axis, touching
+    nothing else: ``buf`` [L, B, ..., Smax, X], ``upd`` [B, ..., S, X].
+    A scalar index writes the whole batch at one offset (the classic
+    lock-step decode); a [B] vector writes each lane at its own offset
+    (continuous batching) as one scatter of B windows. Offsets clamp so
+    the window fits, as ``dynamic_update_slice`` clamps."""
+    i32 = lambda t: jnp.asarray(t, jnp.int32)
+    seq = buf.ndim - 2
     if getattr(index, "ndim", 0) == 0:
-        z = jnp.zeros((), index.dtype)
-        starts = (z, index) + (z,) * (buf.ndim - 2)
-        return jax.lax.dynamic_update_slice(buf, upd, starts)
-
-    def one(b, u, i):
-        starts = (i,) + (jnp.zeros((), i.dtype),) * (b.ndim - 1)
-        return jax.lax.dynamic_update_slice(b, u, starts)
-
-    return jax.vmap(one)(buf, upd, index)
+        starts = [i32(0)] * buf.ndim
+        starts[0], starts[seq] = i32(layer), i32(index)
+        return jax.lax.dynamic_update_slice(buf, upd[None], starts)
+    B = upd.shape[0]
+    where = jnp.stack([jnp.broadcast_to(i32(layer), (B,)),
+                       jnp.arange(B, dtype=jnp.int32), i32(index)], axis=1)
+    dnums = jax.lax.ScatterDimensionNumbers(
+        update_window_dims=tuple(range(1, upd.ndim)),
+        inserted_window_dims=(0, 1),
+        scatter_dims_to_operand_dims=(0, 1, seq))
+    return jax.lax.scatter(buf, where, upd, dnums, indices_are_sorted=True,
+                           unique_indices=True,
+                           mode=jax.lax.GatherScatterMode.CLIP)
 
 
 def _mask5(mask):
@@ -65,7 +92,8 @@ def gqa_attention(
     """Grouped-query attention. x: [B,S,d]. Returns (out, new_cache).
 
     With ``cache`` set this is a decode/prefill step at absolute position
-    ``q_offset``; keys/values are appended into the cache buffers.
+    ``q_offset``; keys/values are appended into layer ``cache.layer`` of
+    the stacked buffers, and the returned cache holds them whole.
     ``fused_decode`` (set by the caller only when the mask is plain causal)
     offers the S==1 step to ``bk.decode_attention`` — the certificate-aware
     flash decode hook; a backend returning None falls back to the composed
@@ -89,25 +117,34 @@ def gqa_attention(
     q = L.apply_rope(bk, q, cos, sin)
     k = L.apply_rope(bk, k, cos, sin)
 
+    # keys/values by position: [B,S,K,Dh] fresh, [B,K,Smax,Dh] from the
+    # cache, whose heads-major layout lets each attention product read
+    # its layer straight out of the stacked buffer
+    kv = "bskd"
     new_cache = None
     if cache is not None:
-        kr = bk.value_of(k).astype(cache.k.dtype)
-        vr = bk.value_of(v).astype(cache.v.dtype)
-        ck = _cache_write(cache.k, kr, cache.index)
-        cv = _cache_write(cache.v, vr, cache.index)
-        new_cache = KVCache(ck, cv, cache.index + S)
+        kr = jnp.swapaxes(bk.value_of(k), 1, 2).astype(cache.k.dtype)
+        vr = jnp.swapaxes(bk.value_of(v), 1, 2).astype(cache.v.dtype)
+        new_cache = cache._replace(
+            k=_cache_write(cache.k, kr, cache.layer, cache.index),
+            v=_cache_write(cache.v, vr, cache.layer, cache.index),
+            index=cache.index + S)
+        ck = layer_of(new_cache.k, cache.layer)
+        cv = layer_of(new_cache.v, cache.layer)
         if fused_decode and S == 1 and not softcap:
             lengths = new_cache.index
             if getattr(lengths, "ndim", 0) == 0:
                 lengths = jnp.full((B,), lengths, jnp.int32)
             q4 = bk.reshape(q, (B, n_kv_heads, G, d_head))
-            fused = bk.decode_attention(q4, ck, cv,
+            fused = bk.decode_attention(q4, jnp.swapaxes(ck, 1, 2),
+                                        jnp.swapaxes(cv, 1, 2),
                                         lengths.astype(jnp.int32))
             if fused is not None:
                 out = bk.reshape(fused, (B, S, n_heads * d_head))
                 return bk.matmul(out, bk.param(p["wo"])), new_cache
         k = bk.input(ck)
         v = bk.input(cv)
+        kv = "bksd"
 
     # group the query heads: [B,S,K,G,Dh]; in training, hint sequence
     # parallelism on q (shards the S×S score tensor over "model")
@@ -115,7 +152,7 @@ def gqa_attention(
         q = bk.shard_hint(q, "q_seq")
     q = bk.reshape(q, (B, S, n_kv_heads, G, d_head))
     scale = d_head ** -0.5
-    scores = bk.einsum("bqkgd,bskd->bkgqs", q, k)
+    scores = bk.einsum(f"bqkgd,{kv}->bkgqs", q, k)
     scores = bk.scale(scores, scale)
     if softcap:
         scores = bk.softcap(scores, softcap)
@@ -123,12 +160,12 @@ def gqa_attention(
     scores = bk.where(_mask5(mask), scores, neg)
     probs = bk.softmax(scores, axis=-1)
     probs = bk.record("attn_probs", probs, kind="softmax")
-    out = bk.einsum("bkgqs,bskd->bqkgd", probs, v)
+    out = bk.einsum(f"bkgqs,{kv}->bqkgd", probs, v)
     if bk.is_analysis:
         # convex-combination fact: Σ_s probs = 1, probs ≥ 0 ⇒ out lies in
         # the value hull (IA cannot see the simplex constraint)
-        vlo = jnp.min(v.exact.lo, axis=1)[:, None, :, None, :]
-        vhi = jnp.max(v.exact.hi, axis=1)[:, None, :, None, :]
+        vlo = jnp.min(v.exact.lo, axis=kv.index("s"))[:, None, :, None, :]
+        vhi = jnp.max(v.exact.hi, axis=kv.index("s"))[:, None, :, None, :]
         out = bk.clamp_range(out, vlo, vhi)
     out = bk.reshape(out, (B, S, n_heads * d_head))
     out = bk.matmul(out, bk.param(p["wo"]))
@@ -195,11 +232,12 @@ def mla_attention(
     if cache is not None:
         cr = bk.value_of(c).astype(cache.k.dtype)
         rr = bk.value_of(k_rope).astype(cache.v.dtype)
-        cc = _cache_write(cache.k, cr, cache.index)
-        crp = _cache_write(cache.v, rr, cache.index)
-        new_cache = KVCache(cc, crp, cache.index + S)
-        c = bk.input(cc)
-        k_rope = bk.input(crp)
+        new_cache = cache._replace(
+            k=_cache_write(cache.k, cr, cache.layer, cache.index),
+            v=_cache_write(cache.v, rr, cache.layer, cache.index),
+            index=cache.index + S)
+        c = bk.input(layer_of(new_cache.k, cache.layer))
+        k_rope = bk.input(layer_of(new_cache.v, cache.layer))
 
     # absorbed scores: q_nope projected into latent space through W_uk
     # wkv_b packs [kv_rank, H*(dn+dv)] → W_uk = [...,:dn], W_uv = [...,dn:]

@@ -283,7 +283,7 @@ def _take_rows(table, positions, Sq):
 
 def _cache_len(cache) -> int:
     if isinstance(cache, dict) and "k" in cache:
-        return int(cache["k"].shape[2])   # [L, B, Smax, ...]
+        return int(cache["k"].shape[-2])  # [L, B, (K,) Smax, X]
     return -1
 
 
@@ -295,17 +295,21 @@ def _one_layer(bk, p, x, i, aux, cfg, cos, sin, gmask, lmask, enc_out,
     if cfg.rwkv:
         state = None
         if aux is not None:
-            state = S.RwkvState(aux["S"], bk.value_of(bk.input(aux["x_tm"])))
+            state = S.RwkvState(
+                A.layer_of(aux["S"], i),
+                bk.value_of(bk.input(A.layer_of(aux["x_tm"], i))))
         out, new_state = S.rwkv_tmix(bk, h, p["tmix"], n_heads=cfg.n_heads,
                                      state=state)
         x = bk.add(x, out)
         h2 = _norm(bk, x, p, cfg, "ln2")
-        cm_prev = None if aux is None else aux["x_cm"]
+        cm_prev = None if aux is None else A.layer_of(aux["x_cm"], i)
         x = bk.add(x, S.rwkv_cmix(bk, h2, p["cmix"], cm_prev))
         if aux is not None:
-            aux_out = {"S": new_state.S.astype(aux["S"].dtype),
-                       "x_tm": new_state.x_prev.astype(aux["x_tm"].dtype),
-                       "x_cm": bk.value_of(h2)[:, -1, :].astype(aux["x_cm"].dtype)}
+            aux_out = {
+                "S": A.with_layer(aux["S"], i, new_state.S),
+                "x_tm": A.with_layer(aux["x_tm"], i, new_state.x_prev),
+                "x_cm": A.with_layer(aux["x_cm"], i,
+                                     bk.value_of(h2)[:, -1, :])}
         return x, aux_out
 
     # pick this layer's mask (gemma2 alternation: even layers local)
@@ -317,7 +321,8 @@ def _one_layer(bk, p, x, i, aux, cfg, cos, sin, gmask, lmask, enc_out,
 
     kv_cache = None
     if aux is not None:
-        kv_cache = A.KVCache(aux["k"], aux["v"], aux["idx"])
+        kv_cache = A.KVCache(aux["k"], aux["v"], A.layer_of(aux["idx"], i),
+                             i)
 
     # named sub-layer scopes: per-scope knobs (formats, range lanes) can
     # resolve layer*/attn and layer*/mlp below per-layer granularity
@@ -339,7 +344,7 @@ def _one_layer(bk, p, x, i, aux, cfg, cos, sin, gmask, lmask, enc_out,
 
     h_ssm_out = None
     if cfg.hybrid:
-        h0 = None if aux is None else aux.get("h_ssm")
+        h0 = None if aux is None else A.layer_of(aux["h_ssm"], i)
         m_out, h_ssm_out = S.mamba_lite(bk, h, p["mamba"],
                                         d_state=cfg.ssm_state, h0=h0,
                                         return_state=True)
@@ -358,9 +363,10 @@ def _one_layer(bk, p, x, i, aux, cfg, cos, sin, gmask, lmask, enc_out,
     x = bk.add(x, mlp_out)
 
     if new_kv is not None:
-        aux_out = {"k": new_kv.k, "v": new_kv.v, "idx": new_kv.index}
+        aux_out = {"k": new_kv.k, "v": new_kv.v,
+                   "idx": A.with_layer(aux["idx"], i, new_kv.index)}
         if h_ssm_out is not None:
-            aux_out["h_ssm"] = h_ssm_out.astype(aux["h_ssm"].dtype)
+            aux_out["h_ssm"] = A.with_layer(aux["h_ssm"], i, h_ssm_out)
     return x, aux_out
 
 
@@ -458,7 +464,9 @@ def init_cache(cfg: ArchConfig, batch: int, max_seq: int,
                dtype=jnp.bfloat16, *,
                per_lane_idx: bool = False) -> Dict[str, jax.Array]:
     """Stacked per-layer decode cache. RWKV: O(1) state. MLA: compressed
-    latent. GQA: [L, B, Smax, K, Dh] keys/values.
+    latent [L, B, Smax, R] and rope key [L, B, Smax, Dr]. GQA: keys and
+    values [L, B, K, Smax, Dh], heads-major so that attention reads a
+    layer in place. Positions run along the second-to-last axis.
 
     ``per_lane_idx=True`` gives each batch lane its own write index
     ([L, B] instead of [L]) — the continuous-batching engine's cache,
@@ -480,8 +488,8 @@ def init_cache(cfg: ArchConfig, batch: int, max_seq: int,
             "idx": idx,
         }
     out = {
-        "k": jnp.zeros((Lh, batch, max_seq, cfg.n_kv_heads, cfg.head_dim), dtype),
-        "v": jnp.zeros((Lh, batch, max_seq, cfg.n_kv_heads, cfg.head_dim), dtype),
+        "k": jnp.zeros((Lh, batch, cfg.n_kv_heads, max_seq, cfg.head_dim), dtype),
+        "v": jnp.zeros((Lh, batch, cfg.n_kv_heads, max_seq, cfg.head_dim), dtype),
         "idx": idx,
     }
     if cfg.hybrid:

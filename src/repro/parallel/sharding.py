@@ -121,8 +121,8 @@ def shard_batch(mesh: Mesh, batch: int, seq: int) -> NamedSharding:
 
 
 def cache_spec(mesh: Mesh, cache_leaf_shape, kind: str) -> P:
-    """Decode caches, stacked [L, B, Smax, ...]:
-      gqa k/v: [L, B, S, K, Dh]; mla: [L, B, S, R]; rwkv S: [L,B,H,C,C].
+    """Decode caches, stacked [L, B, ...]:
+      gqa k/v: [L, B, K, S, Dh]; mla: [L, B, S, R]; rwkv S: [L,B,H,C,C].
     """
     shape = list(cache_leaf_shape)
     spec = [None] * len(shape)
@@ -137,16 +137,16 @@ def cache_spec(mesh: Mesh, cache_leaf_shape, kind: str) -> P:
         seq_data = False
     else:
         seq_data = True
-    if kind == "gqa":  # [L,B,S,K,Dh]
-        S, K = shape[2], shape[3]
+    if kind == "gqa":  # [L,B,K,S,Dh]
+        K, S = shape[2], shape[3]
         if K % m_sz == 0 and K >= m_sz:
-            spec[3] = "model"
-            if seq_data and S % d_sz == 0:
-                spec[2] = "data"
-        elif S % (m_sz * (d_sz if seq_data else 1)) == 0:
-            spec[2] = ("data", "model") if seq_data else "model"
-        elif S % m_sz == 0:
             spec[2] = "model"
+            if seq_data and S % d_sz == 0:
+                spec[3] = "data"
+        elif S % (m_sz * (d_sz if seq_data else 1)) == 0:
+            spec[3] = ("data", "model") if seq_data else "model"
+        elif S % m_sz == 0:
+            spec[3] = "model"
     elif kind == "mla":  # [L,B,S,R]
         S = shape[2]
         div = m_sz * (d_sz if seq_data else 1)
@@ -229,7 +229,7 @@ def shard_params_serving(params, mesh: Mesh, *,
 
 
 def lane_cache_spec(mesh: Mesh, leaf_shape, key: str) -> P:
-    """Per-lane KV cache spec, stacked [L, B, Smax, ...]: lanes over
+    """Per-lane KV cache spec, stacked [L, B, ...]: lanes over
     "data" when divisible, KV heads over "model" when divisible — and
     NEVER the sequence dim over "model" (a sequence split makes XLA build
     the distributed softmax, whose reduction order breaks the engine's
@@ -243,10 +243,10 @@ def lane_cache_spec(mesh: Mesh, leaf_shape, key: str) -> P:
     B = shape[1]
     if d_sz > 1 and B % d_sz == 0 and B >= d_sz:
         spec[1] = "data"
-    if key in ("k", "v") and len(shape) == 5:       # gqa [L,B,S,K,Dh]
-        K = shape[3]
+    if key in ("k", "v") and len(shape) == 5:       # gqa [L,B,K,S,Dh]
+        K = shape[2]
         if m_sz > 1 and K % m_sz == 0 and K >= m_sz:
-            spec[3] = "model"
+            spec[2] = "model"
     return P(*spec)
 
 

@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 
 from repro import configs
-from repro.core.backend import JOps
+from repro.core.backend import JOps, UnrolledLayerLoop
 from repro.models import transformer as T
 
 
@@ -111,6 +111,68 @@ def test_decode_matches_full_forward_rwkv():
     step_logits = jnp.stack(outs, axis=1)
     np.testing.assert_allclose(np.asarray(step_logits),
                                np.asarray(full_logits), rtol=5e-3, atol=5e-3)
+
+
+class _UnrolledJOps(UnrolledLayerLoop, JOps):
+    pass
+
+
+@pytest.mark.parametrize("arch", ["qwen2_7b", "minicpm3_4b", "rwkv6_1p6b",
+                                  "hymba_1p5b"])
+def test_scanned_cache_carry_matches_unrolled(arch):
+    """The scanned layer loop threads the stacked cache through its carry;
+    the unrolled reference runs the same layer function with a static
+    layer index. A chunk then a decode step at batch 3, each lane at its
+    own position, give bitwise-equal logits and new caches, for each kind
+    of cache: GQA K/V, MLA latent, RWKV state, hybrid K/V + SSM state."""
+    cfg = configs.get(arch).SMOKE
+    params = T.init_params(jax.random.PRNGKey(6), cfg)
+    rng = np.random.RandomState(6)
+    B, Smax = 3, 16
+    # prior contents everywhere, so a read or write of the wrong layer or
+    # lane shows in the logits or the cache
+    cache = {n: (a if a.dtype == jnp.int32 else
+                 jnp.asarray(0.1 * rng.randn(*a.shape), a.dtype))
+             for n, a in T.init_cache(cfg, B, Smax, jnp.float32,
+                                      per_lane_idx=True).items()}
+    offsets = jnp.asarray([0, 5, 11], jnp.int32)
+    if "idx" in cache:
+        cache["idx"] = jnp.broadcast_to(offsets, cache["idx"].shape)
+
+    # params are arguments, as in the engine's programs: closed over, they
+    # become constants that XLA:CPU folds into other kernels in each form
+    def step(bk):
+        return jax.jit(lambda p, c, t, o: T.forward(bk, p, cfg, t, cache=c,
+                                                    q_offset=o))
+
+    scanned, unrolled = step(JOps()), step(_UnrolledJOps())
+    got_c = want_c = cache
+    for t, o in ((2, offsets), (1, offsets + 2)):
+        tokens = jnp.asarray(rng.randint(0, cfg.vocab, (B, t)), jnp.int32)
+        got, got_c = scanned(params, got_c, tokens, o)
+        want, want_c = unrolled(params, want_c, tokens, o)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        assert sorted(got_c) == sorted(cache)
+        for n in cache:
+            np.testing.assert_array_equal(np.asarray(got_c[n]),
+                                          np.asarray(want_c[n]), err_msg=n)
+    # the two steps wrote every layer, and each lane's KV rows only at its
+    # own three positions (the second-to-last axis), nothing else
+    for n in cache:
+        changed = np.asarray(got_c[n] != cache[n])
+        assert changed.reshape(changed.shape[0], -1).any(axis=1).all(), n
+    pos = np.arange(Smax)[None, :]
+    lo = np.asarray(offsets)[:, None]
+    for n in ("k", "v") if "idx" in cache else ():
+        changed = np.asarray(got_c[n] != cache[n]).any(axis=-1)
+        changed = changed.any(axis=2) if changed.ndim == 4 else changed
+        np.testing.assert_array_equal(
+            changed, np.broadcast_to((pos >= lo) & (pos < lo + 3),
+                                     changed.shape), err_msg=n)
+    if "idx" in cache:
+        np.testing.assert_array_equal(
+            np.asarray(got_c["idx"]),
+            np.broadcast_to(np.asarray(offsets) + 3, cache["idx"].shape))
 
 
 def test_full_configs_match_assignment():

@@ -233,13 +233,13 @@ def test_pad_contents_do_not_reach_real_rows(params):
     lg1, c1 = prefill(zero_pad)
     lg2, c2 = prefill(junk_pad)
     assert bool(jnp.array_equal(lg1[0, :6], lg2[0, :6]))
-    assert bool(jnp.array_equal(c1["k"][:, :, :6], c2["k"][:, :, :6]))
-    assert bool(jnp.array_equal(c1["v"][:, :, :6], c2["v"][:, :, :6]))
+    assert bool(jnp.array_equal(c1["k"][:, :, :, :6], c2["k"][:, :, :, :6]))
+    assert bool(jnp.array_equal(c1["v"][:, :, :, :6], c2["v"][:, :, :, :6]))
     lg0, c0 = prefill(toks)
     np.testing.assert_allclose(lg0[0], lg1[0, :6], rtol=1e-5, atol=1e-6)
-    np.testing.assert_allclose(c0["k"][:, :, :6], c1["k"][:, :, :6],
+    np.testing.assert_allclose(c0["k"][:, :, :, :6], c1["k"][:, :, :, :6],
                                rtol=1e-5, atol=1e-6)
-    np.testing.assert_allclose(c0["v"][:, :, :6], c1["v"][:, :, :6],
+    np.testing.assert_allclose(c0["v"][:, :, :, :6], c1["v"][:, :, :, :6],
                                rtol=1e-5, atol=1e-6)
 
 
@@ -398,3 +398,59 @@ def test_compiled_decode_names_the_model_scopes(params, fmt):
         assert any(s[s.index("layer*"):s.index("layer*") + 3]
                    == ["layer*", "while", "body"]
                    for s in segs if scope in s and "layer*" in s), scope
+
+
+def _scans(jaxpr):
+    """Every ``scan`` equation of a jaxpr, nested jaxprs included."""
+    from jax.extend import core as jcore
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            yield eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                if isinstance(sub, jcore.ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, jcore.Jaxpr):
+                    yield from _scans(sub)
+
+
+@pytest.mark.parametrize("fmt", [None, {"": {"k": 11, "emax": 15,
+                                             "emin": -14}}])
+def test_decode_threads_the_cache_through_the_scan_carry(params, fmt):
+    """The decode program updates the stacked cache in place: its K and V
+    enter the layer scan as carry, and no scan output or zero fill has a
+    stacked cache leaf's shape. (A cache passed to the scan as input and
+    output is sliced per layer, rewritten whole into zero-filled stacked
+    outputs and copied out, every step.)"""
+    sc = serve.ServeConfig(arch="qwen2_7b", batch=2, max_seq=32,
+                           precision_layer_format=fmt)
+    eng = ContinuousBatchingEngine(CFG, sc, params, n_lanes=2, max_seq=32,
+                                   page_size=8)
+    lanes = jnp.zeros((2,), jnp.int32)
+    args = (eng.params, eng.cache, lanes, lanes)
+    (call,) = jax.make_jaxpr(eng._decode)(*args).jaxpr.eqns
+    program = call.params["jaxpr"].jaxpr
+    n_params = len(jax.tree_util.tree_leaves(eng.params))
+    cache_in = dict(zip(sorted(eng.cache), program.invars[n_params:]))
+    stacked = {tuple(eng.cache[n].shape) for n in ("k", "v")}
+
+    scans = list(_scans(program))
+    layer_scans = [e for e in scans if e.params["length"] == CFG.n_layers]
+    assert len(layer_scans) == 1
+    (scan,) = layer_scans
+    c0 = scan.params["num_consts"]
+    carry = scan.invars[c0:c0 + scan.params["num_carry"]]
+    for name in ("k", "v"):
+        assert any(v is cache_in[name] for v in carry), name
+    carried = {(tuple(v.aval.shape), v.aval.dtype) for v in carry}
+    for name, leaf in eng.cache.items():
+        assert (tuple(leaf.shape), leaf.dtype) in carried, name
+    for e in scans:
+        ys = e.outvars[e.params["num_carry"]:]
+        assert not {tuple(v.aval.shape) for v in ys} & stacked
+
+    text = eng._decode.lower(*args).as_text()
+    for shape in stacked:
+        ty = "x".join(map(str, shape))
+        assert not [ln for ln in text.splitlines()
+                    if "broadcast_in_dim" in ln and f"tensor<{ty}x" in ln]

@@ -56,21 +56,21 @@ def test_batch_spec_prefers_batch_then_seq():
 
 
 def test_cache_spec_gqa_heads_divisible():
-    # [L,B,S,K,Dh] with K=16 divisible by model → heads sharded
-    spec = sh.cache_spec(SINGLE, (46, 128, 32768, 16, 128), "gqa")
-    assert spec[3] == "model" and spec[1] == "data"
+    # [L,B,K,S,Dh] with K=16 divisible by model → heads sharded
+    spec = sh.cache_spec(SINGLE, (46, 128, 16, 32768, 128), "gqa")
+    assert spec[2] == "model" and spec[1] == "data"
 
 
 def test_cache_spec_gqa_seq_fallback():
     # K=8 not divisible by 16 → KV-sequence over model (flash-style)
-    spec = sh.cache_spec(SINGLE, (28, 128, 32768, 8, 128), "gqa")
-    assert spec[2] == "model" and spec[3] is None
+    spec = sh.cache_spec(SINGLE, (28, 128, 8, 32768, 128), "gqa")
+    assert spec[3] == "model" and spec[2] is None
 
 
 def test_cache_spec_batch1_long_context():
-    spec = sh.cache_spec(SINGLE, (24, 1, 524288, 8, 128), "gqa")
+    spec = sh.cache_spec(SINGLE, (24, 1, 8, 524288, 128), "gqa")
     # batch 1: sequence takes both axes
-    assert spec[2] in (("data", "model"), "model")
+    assert spec[3] in (("data", "model"), "model")
 
 
 def test_cache_spec_mla_latent():
