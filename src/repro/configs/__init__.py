@@ -37,6 +37,7 @@ ARCHS = [
     "minicpm3_4b",
     "whisper_medium",
     "paligemma_3b",
+    "deepseek_v2_lite",
 ]
 
 _ALIASES = {
@@ -50,6 +51,7 @@ _ALIASES = {
     "minicpm3-4b": "minicpm3_4b",
     "whisper-medium": "whisper_medium",
     "paligemma-3b": "paligemma_3b",
+    "deepseek-v2-lite": "deepseek_v2_lite",
 }
 
 
@@ -61,7 +63,7 @@ def get(name: str):
 
 
 def cells(include_skipped: bool = False):
-    """All 40 (arch × shape) assignment cells; marks the documented skips."""
+    """All (arch × shape) assignment cells; marks the documented skips."""
     out = []
     for a in ARCHS:
         mod = get(a)

@@ -163,9 +163,15 @@ class Backend:
 
     # structure
     def layer_loop(self, fn: Callable, stacked_params, x, n_layers: int,
-                   aux=None):
+                   aux=None, first: int = 0):
         """Apply ``fn(layer_params, x, layer_index, aux) -> (x, aux)``
         across layers. Returns (x, aux).
+
+        ``first`` numbers the stack's layers from there: a model whose
+        leading layers are a stack of their own (DeepSeek's dense layer
+        before its MoE layers) runs them first, then this stack as layers
+        ``first .. first + n_layers - 1``. ``layer_index`` and the scope
+        names count across both; ``stacked_params`` is indexed from 0.
 
         ``aux`` is an optional pytree of stacked ``[L, ...]`` state (the
         decode cache), handed whole from layer to layer: layer ``i`` reads
@@ -204,6 +210,26 @@ class Backend:
         einsum/softmax path (the default). Certified serving backends
         override this with the certificate-aware flash decode kernel."""
         return None
+
+    def grouped_matmul(self, x, w, layer, routes):
+        """Routed-expert product: rows of ``x`` [R, K] come in tiles of
+        ``routes.tm`` rows, tile t all routed to expert
+        ``routes.tile_expert[t]``; each row times its expert's weights in
+        layer ``layer`` of the whole stacked ``w`` [L, E, K, N]; rows that
+        hold no pair are zero and give zero rows. This default runs
+        :meth:`matmul` tile by tile (the quantised serving backends'
+        arithmetic)."""
+        K, N = w.shape[2:]
+        w_layer = jax.lax.dynamic_index_in_dim(w, layer, 0, keepdims=False)
+
+        def one_tile(args):
+            rows, e = args
+            return self.matmul(rows, jax.lax.dynamic_index_in_dim(
+                w_layer, e, 0, keepdims=False))
+
+        out = jax.lax.map(one_tile, (x.reshape(-1, routes.tm, K),
+                                     routes.tile_expert))
+        return out.reshape(-1, N)
 
 
 # ---------------------------------------------------------------------------
@@ -322,13 +348,14 @@ class JOps(Backend):
         spec = P(dp if dp else None, *([None] * (a.ndim - 1)))
         return jax.lax.with_sharding_constraint(a, NamedSharding(mesh, spec))
 
-    def layer_loop(self, fn, stacked_params, x, n_layers: int, aux=None):
+    def layer_loop(self, fn, stacked_params, x, n_layers: int, aux=None,
+                   first: int = 0):
         # one traced body serves every layer: it runs under the stacked
         # wildcard scope, which the per-scope maps key as layer*/...
         def body(carry, xs):
             h, a = carry
             p, i = xs
-            h, a = fn(p, h, i, a)
+            h, a = fn(p, h, i + first, a)
             return (self.shard_hint(h, "act_batch"), a), None
 
         idx = jnp.arange(n_layers)
@@ -336,6 +363,15 @@ class JOps(Backend):
             (out, aux), _ = jax.lax.scan(body, (x, aux),
                                          (stacked_params, idx))
         return out, aux
+
+    # dropless routed experts: the rows of each expert's tiles form one
+    # group of a ragged product over that layer's [E, K, N] weights
+    def grouped_matmul(self, x, w, layer, routes):
+        w_layer = jax.lax.dynamic_index_in_dim(w, layer, 0, keepdims=False)
+        out = jax.lax.ragged_dot(x, w_layer, routes.group_sizes,
+                                 precision=jax.lax.Precision.HIGHEST,
+                                 preferred_element_type=self.accum_dtype)
+        return out.astype(self.compute_dtype)
 
     def ssm_scan(self, decay, drive, n_steps: int, time_axis: int = 1):
         dec = jnp.moveaxis(decay, time_axis, 0)
@@ -365,11 +401,12 @@ class UnrolledLayerLoop:
     must never diverge, since certificates are confirmed on the former and
     bit-for-bit checked against the latter."""
 
-    def layer_loop(self, fn, stacked_params, x, n_layers: int, aux=None):
+    def layer_loop(self, fn, stacked_params, x, n_layers: int, aux=None,
+                   first: int = 0):
         for i in range(n_layers):
             layer_params = jax.tree_util.tree_map(lambda p: p[i], stacked_params)
-            with self.scope(f"layer{i}"):
-                x, aux = fn(layer_params, x, i, aux)
+            with self.scope(f"layer{first + i}"):
+                x, aux = fn(layer_params, x, first + i, aux)
         return x, aux
 
 
@@ -792,7 +829,12 @@ class StackedCaaOps(CaaOps):
     def _finish_stack_state(self, state):
         pass
 
-    def layer_loop(self, fn, stacked_params, x, n_layers: int, aux=None):
+    def layer_loop(self, fn, stacked_params, x, n_layers: int, aux=None,
+                   first: int = 0):
+        if first:
+            raise NotImplementedError(
+                "the scan-form analyses cover one homogeneous layer stack; "
+                "analyse a model with leading layers on the eager unroll")
         if self._in_stack:
             # nested stacks are out of scope for the scan form — fall back
             # to the eager unroll for the inner loop
@@ -1594,7 +1636,12 @@ class StackedAffineRangeCaaOps(AffineRangeCaaOps):
             StackedRangeCaaOps._merge_acc(self._lane_acc[i, j], stat))
 
     # -- the one scan --------------------------------------------------------
-    def layer_loop(self, fn, stacked_params, x, n_layers: int, aux=None):
+    def layer_loop(self, fn, stacked_params, x, n_layers: int, aux=None,
+                   first: int = 0):
+        if first:
+            raise NotImplementedError(
+                "the scan-form analyses cover one homogeneous layer stack; "
+                "analyse a model with leading layers on the eager unroll")
         if self._in_stack:
             return super().layer_loop(fn, stacked_params, x, n_layers, aux)
         outer = list(self._scope)

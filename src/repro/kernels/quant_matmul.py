@@ -123,8 +123,12 @@ def quant_matmul_format_ref(x: jax.Array, w: jax.Array, fmt,
     wq = q(jnp.asarray(w, jnp.float32))
     K, N = wq.shape
     bk = format_block_k(K)
-    xb = jnp.moveaxis(xq.reshape(*xq.shape[:-1], K // bk, bk), -2, 0)
-    wb = wq.reshape(K // bk, bk, N)
+    nk = -(-K // bk)
+    if nk * bk != K:        # the kernel's last block is masked to zeros
+        xq = jnp.pad(xq, [(0, 0)] * (xq.ndim - 1) + [(0, nk * bk - K)])
+        wq = jnp.pad(wq, ((0, nk * bk - K), (0, 0)))
+    xb = jnp.moveaxis(xq.reshape(*xq.shape[:-1], nk, bk), -2, 0)
+    wb = wq.reshape(nk, bk, N)
 
     def add_block(acc, blk):
         xk, wk = blk
@@ -144,11 +148,18 @@ def smem_format(fmt_ref):
     return tuple(jnp.full((1, 1), fmt_ref[i], jnp.int32) for i in range(3))
 
 
-def _quant_matmul_format_kernel(fmt_ref, x_ref, w_ref, o_ref, acc, *,
-                                n_k_steps: int, has_subnormals: bool,
-                                saturating: bool):
+def _quant_matmul_format_kernel(fmt_ref, *refs, n_k_steps: int,
+                                has_subnormals: bool, saturating: bool,
+                                n_scalar: int = 0, k_tail: int = 0):
+    """One (row block, column block, K step) of the format GEMM. The dense
+    grid and the grouped grid share it: ``refs`` opens with ``n_scalar``
+    further scalar-prefetch refs, which only the grouped grid's index maps
+    read. ``k_tail`` > 0 is the width of a last K block that overhangs
+    the contraction: its columns of x and rows of w past the end are
+    masked to zero, as the eager mirror pads them."""
     from repro.core.quantize import quantize_to_format
 
+    x_ref, w_ref, o_ref, acc = refs[n_scalar:]
     k, emax, emin = smem_format(fmt_ref)
     q = lambda v: quantize_to_format(v, k, emax, emin,
                                      has_subnormals, saturating)
@@ -157,9 +168,17 @@ def _quant_matmul_format_kernel(fmt_ref, x_ref, w_ref, o_ref, acc, *,
     def _init():
         acc[...] = jnp.zeros_like(acc)
 
-    acc[...] += jnp.dot(q(x_ref[...].astype(jnp.float32)),
-                        q(w_ref[...].astype(jnp.float32)),
-                        precision=HIGHEST,
+    x = x_ref[...].astype(jnp.float32)
+    w = w_ref[...].astype(jnp.float32)
+    if k_tail:
+        i32 = lambda v: jnp.full((1, 1), v, jnp.int32)   # no 64-bit scalars
+        width = jnp.where(pl.program_id(2) == n_k_steps - 1, i32(k_tail),
+                          i32(x.shape[1]))
+        x = jnp.where(jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+                      < width, x, 0.0)
+        w = jnp.where(jax.lax.broadcasted_iota(jnp.int32, w.shape, 0)
+                      < width, w, 0.0)
+    acc[...] += jnp.dot(q(x), q(w), precision=HIGHEST,
                         preferred_element_type=jnp.float32)
 
     @pl.when(pl.program_id(2) == n_k_steps - 1)
@@ -182,20 +201,22 @@ def quant_matmul_format(x: jax.Array, w: jax.Array, fmt, *,
     semantics are exactly :func:`quant_matmul_format_ref`'s; with
     ``block_k = format_block_k(K)`` the two also sum the contraction in
     the same blocks and order — the acceptance test for v3 certificates
-    serves through both and compares bits.
+    serves through both and compares bits. N and K need not be whole
+    blocks: a last column block overhangs into columns that are dropped,
+    a last K block is masked.
     """
     M, K = x.shape
     K2, N = w.shape
     assert K == K2
     bm, bn, bk = min(block_m, M), min(block_n, N), min(block_k, K)
-    assert M % bm == 0 and N % bn == 0 and K % bk == 0
-    nk = K // bk
+    assert M % bm == 0
+    nk = -(-K // bk)
     kernel = functools.partial(_quant_matmul_format_kernel, n_k_steps=nk,
                                has_subnormals=has_subnormals,
-                               saturating=saturating)
+                               saturating=saturating, k_tail=K % bk)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(M // bm, N // bn, nk),
+        grid=(M // bm, -(-N // bn), nk),
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk, fmt_ref: (i, kk)),
             pl.BlockSpec((bk, bn), lambda i, j, kk, fmt_ref: (kk, j)),
@@ -222,10 +243,12 @@ def _pick_block(dim: int, target: int) -> int:
 
 
 def format_block_k(K: int) -> int:
-    """Contraction block of the format GEMM, shared by the kernel and its
-    eager mirror. 512 keeps an f32 weight tile at 512 KiB (a whole
-    18944-deep down-projection column block would be 19 MB of VMEM)."""
-    return _pick_block(K, 512)
+    """Contraction block of the format GEMM, shared by the kernels and
+    their eager mirrors. 512 keeps an f32 weight tile at 512 KiB (a whole
+    18944-deep down-projection column block would be 19 MB of VMEM) and
+    is a whole number of 128-wide lanes; a K that is no multiple of it
+    ends in a shorter block (10944 = 21 x 512 + 192)."""
+    return min(K, 512)
 
 
 def quant_matmul_format_dispatch(x: jax.Array, w: jax.Array, fmt,
@@ -258,9 +281,127 @@ def quant_matmul_format_dispatch(x: jax.Array, w: jax.Array, fmt,
     out = quant_matmul_format(
         jnp.asarray(x, jnp.float32).reshape(M, K), jnp.asarray(w, jnp.float32),
         fmt, has_subnormals=has_subnormals, saturating=saturating,
-        block_m=_pick_block(M, 256), block_n=_pick_block(N, 256),
+        block_m=_pick_block(M, 256), block_n=256,
         block_k=format_block_k(K), interpret=interpret)
     return out.reshape(*lead, N)
+
+
+def _grouped_block_n(bk: int, N: int) -> int:
+    """Column block of the grouped GEMM: every column at once while an f32
+    weight block stays within 4 MiB (so a tile walks its expert's weights
+    in a few large steps), else 256."""
+    return N if bk * N * 4 <= 4 << 20 else 256
+
+
+def grouped_quant_matmul_format(x: jax.Array, w: jax.Array, tile_expert,
+                                meta, fmt, *, tm: int,
+                                has_subnormals: bool = True,
+                                saturating: bool = True,
+                                interpret: bool = False):
+    """Grouped format GEMM over stacked expert weights, read in place.
+
+    ``x`` [R, K] holds rows in tiles of ``tm``; every row of tile t goes
+    to expert ``tile_expert[t]`` of layer ``meta[0]`` of ``w`` [L, E, K,
+    N]; ``meta[1]`` tiles are real, the rest pad the grid to its static
+    size. The grid is the dense kernel's (tile, column block, K step)
+    around the same body, with the format, the tile→expert table and
+    ``meta`` prefetched as scalars: the weight block of step (t, j, kk) is
+    block (layer, expert[t], kk, j) of the whole stack, so no layer or
+    expert is copied out of it. A padding tile reads the blocks of the
+    last real tile's last step again, which its index does not change, so
+    nothing is fetched for it; its rows are unspecified here (the
+    dispatch zeroes them). Operand and result rounding are the dense
+    kernel's."""
+    R, K = x.shape
+    _, _, K2, N = w.shape
+    assert K == K2 and R % tm == 0
+    bk = format_block_k(K)
+    nk = -(-K // bk)
+    bn = _grouped_block_n(bk, N)
+    nj = -(-N // bn)
+    kernel = functools.partial(_quant_matmul_format_kernel, n_k_steps=nk,
+                               has_subnormals=has_subnormals,
+                               saturating=saturating, n_scalar=2,
+                               k_tail=K % bk)
+
+    # the index maps stay in i32: Mosaic lowers no 64-bit index (x64 mode)
+    def pick(real, a, b):
+        return jax.lax.select(real, jnp.asarray(a, jnp.int32),
+                              jnp.asarray(b, jnp.int32))
+
+    def x_map(t, j, kk, fmt_ref, te_ref, meta_ref):
+        real = t < meta_ref[1]
+        return pick(real, t, meta_ref[1] - 1), pick(real, kk, nk - 1)
+
+    def w_map(t, j, kk, fmt_ref, te_ref, meta_ref):
+        real = t < meta_ref[1]
+        return (meta_ref[0], te_ref[t], pick(real, kk, nk - 1),
+                pick(real, j, nj - 1))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(R // tm, nj, nk),
+        in_specs=[pl.BlockSpec((tm, bk), x_map),
+                  pl.BlockSpec((None, None, bk, bn), w_map)],
+        out_specs=pl.BlockSpec((tm, bn), lambda t, j, kk, *_: (t, j)),
+        scratch_shapes=[pltpu.VMEM((tm, bn), jnp.float32)],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((R, N), jnp.float32),
+        # two 4 MiB weight blocks and their rounded copies pass the 16 MiB
+        # default scope; a v5e core has 128 MiB of VMEM
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=48 << 20),
+        interpret=interpret,
+    )(jnp.asarray(fmt, jnp.int32), jnp.asarray(tile_expert, jnp.int32),
+      jnp.asarray(meta, jnp.int32), x, w)
+
+
+def grouped_quant_matmul_format_ref(x: jax.Array, w: jax.Array, tile_expert,
+                                    meta, fmt, *, tm: int,
+                                    has_subnormals: bool = True,
+                                    saturating: bool = True) -> jax.Array:
+    """Eager mirror of :func:`grouped_quant_matmul_format`: tile by tile,
+    :func:`quant_matmul_format_ref` of the tile's rows with its expert's
+    weights, so each contraction is summed in :func:`format_block_k`
+    blocks in the kernel's order (zero rows give zero rows)."""
+    R, K = x.shape
+    layer = jnp.asarray(meta, jnp.int32)[0]
+    w_layer = jax.lax.dynamic_index_in_dim(w, layer, 0, keepdims=False)
+
+    def one_tile(args):
+        rows, e = args
+        we = jax.lax.dynamic_index_in_dim(w_layer, e, 0, keepdims=False)
+        return quant_matmul_format_ref(rows, we, fmt, has_subnormals,
+                                       saturating)
+
+    out = jax.lax.map(one_tile, (jnp.asarray(x, jnp.float32).reshape(
+        R // tm, tm, K), jnp.asarray(tile_expert, jnp.int32)))
+    return out.reshape(R, -1)
+
+
+def grouped_quant_matmul_format_dispatch(x, w, tile_expert, meta, fmt, *,
+                                         tm: int, has_subnormals=True,
+                                         saturating=True, force_kernel=None,
+                                         interpret: bool = False):
+    """Serving dispatch of the grouped format GEMM: the kernel on TPU, its
+    eager mirror elsewhere (``force_kernel`` as in
+    :func:`quant_matmul_format_dispatch`). The rows of padding tiles,
+    which the kernel leaves unspecified, are zeroed, as the mirror makes
+    them from zero rows."""
+    use_kernel = force_kernel
+    if use_kernel is None:
+        use_kernel = jax.default_backend() == "tpu"
+    kw = dict(tm=tm, has_subnormals=has_subnormals, saturating=saturating)
+    if not use_kernel:
+        return grouped_quant_matmul_format_ref(x, w, tile_expert, meta, fmt,
+                                               **kw)
+    out = grouped_quant_matmul_format(
+        jnp.asarray(x, jnp.float32), jnp.asarray(w, jnp.float32),
+        tile_expert, meta, fmt, interpret=interpret, **kw)
+    rows = jnp.arange(out.shape[0], dtype=jnp.int32)[:, None]
+    return jnp.where(rows < jnp.asarray(meta, jnp.int32)[1] * tm, out, 0.0)
 
 
 def quant_matmul(x: jax.Array, w: jax.Array, *, k: int,

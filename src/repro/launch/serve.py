@@ -28,7 +28,8 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import configs, obs
-from repro.core.backend import JOps, UnrolledLayerLoop  # noqa: F401 — the
+from repro.core.backend import Backend, JOps
+from repro.core.backend import UnrolledLayerLoop  # noqa: F401 — the
 # unrolled mixin is re-exported here as the serving-side differential
 # baseline (compose it in front of a scanned backend; see tests/examples)
 from repro.models import transformer as T
@@ -121,7 +122,11 @@ class QuantJOps(JOps):
         _emit_health(self, out, self._k)
         return _quantize_normal(out, self._k).astype(self.compute_dtype)
 
-    def layer_loop(self, fn, stacked_params, x, n_layers: int, aux=None):
+    # routed experts tile by tile through the k-bit matmul above
+    grouped_matmul = Backend.grouped_matmul
+
+    def layer_loop(self, fn, stacked_params, x, n_layers: int, aux=None,
+                   first: int = 0):
         # one traced body serves every layer, so monitor observations from
         # inside the scan carry the stacked wildcard scope JOps.layer_loop
         # pushes (matching the certificate's layer* / layer<i> envelope
@@ -130,7 +135,8 @@ class QuantJOps(JOps):
         # attribution of compile cost
         with obs.span("serve.layer_scan", backend=type(self).__name__,
                       layers=n_layers):
-            return super().layer_loop(fn, stacked_params, x, n_layers, aux)
+            return super().layer_loop(fn, stacked_params, x, n_layers, aux,
+                                      first)
 
 
 class _SuffixLanes:
@@ -157,12 +163,12 @@ class _SuffixLanes:
         self._dyn = None
 
     def _suffix_lane(self):
-        outer, n_layers = self._stack_ctx
+        outer, n_layers, first = self._stack_ctx
         suffix = tuple(self.scope_path[len(outer) + 1:])
         lane = self._lane_cache.get(suffix)
         if lane is None:
             lane = jnp.asarray(
-                [self._lane_static(outer + [f"layer{i}", *suffix])
+                [self._lane_static(outer + [f"layer{first + i}", *suffix])
                  for i in range(n_layers)], jnp.int32)
             self._lane_cache[suffix] = lane
         return lane
@@ -176,15 +182,16 @@ class _SuffixLanes:
                 and getattr(self, "_layer_idx", None) is not None):
             self._refresh_dyn()
 
-    def _lane_loop(self, fn, stacked_params, x, n_layers, aux, super_loop):
+    def _lane_loop(self, fn, stacked_params, x, n_layers, aux, super_loop,
+                   first: int = 0):
         # super_loop (JOps.layer_loop) pushes the one layer* scope level
         # that the suffix lanes look past
         outer = list(self.scope_path)
-        self._stack_ctx = (outer, n_layers)
+        self._stack_ctx = (outer, n_layers, first)
         self._lane_cache = {}
 
         def scoped_fn(p, carry, i, a):
-            self._layer_idx = i
+            self._layer_idx = i - first
             self._refresh_dyn()
             try:
                 return fn(p, carry, i, a)
@@ -196,7 +203,7 @@ class _SuffixLanes:
             with obs.span("serve.layer_scan", backend=type(self).__name__,
                           layers=n_layers):
                 return super_loop(scoped_fn, stacked_params, x,
-                                  n_layers, aux)
+                                  n_layers, aux, first)
         finally:
             self._stack_ctx = None
             self._lane_cache = {}
@@ -241,9 +248,12 @@ class MixedQuantJOps(_SuffixLanes, JOps):
         _emit_health(self, out, k)
         return out.astype(self.compute_dtype)
 
-    def layer_loop(self, fn, stacked_params, x, n_layers: int, aux=None):
+    grouped_matmul = Backend.grouped_matmul
+
+    def layer_loop(self, fn, stacked_params, x, n_layers: int, aux=None,
+                   first: int = 0):
         return self._lane_loop(fn, stacked_params, x, n_layers, aux,
-                               super().layer_loop)
+                               super().layer_loop, first)
 
 
 class _FmtTriple:
@@ -369,6 +379,32 @@ class FormatQuantJOps(_SuffixLanes, JOps):
         _emit_health(self, out, fmt[0], fmt[1], fmt[2])
         return out.astype(self.compute_dtype)
 
+    def grouped_matmul(self, x, w, layer, routes):
+        """The routed experts' products through the grouped format GEMM,
+        in the scope's format, over the whole stacked expert weights: the
+        kernel fetches layer ``layer``'s blocks in place. One device
+        only: no layer shares the experts out over a mesh yet, and run on
+        each device the product would read every expert there."""
+        from repro.kernels.quant_matmul import (
+            grouped_quant_matmul_format_dispatch)
+        if self.mesh is not None and self.mesh.devices.size > 1:
+            raise NotImplementedError(
+                "grouped format GEMM on a multi-device mesh: the routed "
+                "experts would be replicated on every device")
+        fmt = self._current_fmt()
+        meta = jnp.stack([jnp.asarray(layer, jnp.int32),
+                          jnp.asarray(routes.n_real, jnp.int32)])
+
+        def gemm(x, w, tile_expert, meta, fmt):
+            return grouped_quant_matmul_format_dispatch(
+                x, w, tile_expert, meta, fmt, tm=routes.tm,
+                has_subnormals=self.has_subnormals,
+                saturating=self.saturating, force_kernel=self.force_kernel)
+
+        out = gemm(x, w, routes.tile_expert, meta, fmt)
+        _emit_health(self, out, fmt[0], fmt[1], fmt[2])
+        return out.astype(self.compute_dtype)
+
     def decode_attention(self, q, k, v, lengths):
         if not self.use_flash_decode:
             return None
@@ -389,9 +425,10 @@ class FormatQuantJOps(_SuffixLanes, JOps):
             (lanes, heads, None, None))
         return out.astype(self.compute_dtype)
 
-    def layer_loop(self, fn, stacked_params, x, n_layers: int, aux=None):
+    def layer_loop(self, fn, stacked_params, x, n_layers: int, aux=None,
+                   first: int = 0):
         return self._lane_loop(fn, stacked_params, x, n_layers, aux,
-                               super().layer_loop)
+                               super().layer_loop, first)
 
 
 def _backend(sc: ServeConfig, mesh=None, monitor=None):

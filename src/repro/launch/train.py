@@ -92,7 +92,8 @@ class RematJOps(JOps):
                 a, NamedSharding(mesh, spec))
         return a
 
-    def layer_loop(self, fn, stacked_params, x, n_layers: int, aux=None):
+    def layer_loop(self, fn, stacked_params, x, n_layers: int, aux=None,
+                   first: int = 0):
         def fn_constrained(p, h, i, a):
             h, a = fn(p, h, i, a)
             return self._residual_constraint(h), a
@@ -101,7 +102,7 @@ class RematJOps(JOps):
 
         def body(carry, xs):
             (h, a), (p, i) = carry, xs
-            return fn_r(p, h, i, a), None
+            return fn_r(p, h, i + first, a), None
         idx = jnp.arange(n_layers)
         x = self._residual_constraint(x)
         (out, aux), _ = jax.lax.scan(body, (x, aux), (stacked_params, idx))

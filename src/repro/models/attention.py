@@ -194,15 +194,19 @@ def init_gqa(key, d: int, n_heads: int, n_kv_heads: int, d_head: int,
 
 def mla_attention(
     bk, x, p, *,
-    n_heads: int, q_rank: int, kv_rank: int,
+    n_heads: int, q_rank: Optional[int], kv_rank: int,
     d_nope: int, d_rope: int, d_v: int,
     cos, sin, mask,
     cache: Optional[KVCache] = None,
     q_offset=0,
+    softmax_scale: Optional[float] = None,
 ):
-    """MLA: queries via low-rank down/up; KV via a shared compressed latent
-    (cached) + a shared rope key. Decode uses the absorbed form (scores in
-    latent space) so the cache stays [B,S,kv_rank(+d_rope)].
+    """MLA: queries via low-rank down/up (straight from ``wq`` where
+    ``q_rank`` is None, as in DeepSeek-V2-Lite); KV via a shared
+    compressed latent (cached) + a shared rope key. Decode uses the
+    absorbed form (scores in latent space) so the cache stays
+    [B,S,kv_rank(+d_rope)]. Scores are scaled by ``softmax_scale``,
+    (d_nope + d_rope)^-1/2 by default.
 
     Chained low-rank GEMMs are exactly two γ_n contractions in the CAA view
     (see DESIGN.md arch table)."""
@@ -210,9 +214,12 @@ def mla_attention(
     H = n_heads
 
     # --- queries ---
-    qc = bk.matmul(x, bk.param(p["wq_a"]))              # [B,S,q_rank]
-    qc = L.rmsnorm(bk, qc, p["q_norm"])
-    q = bk.matmul(qc, bk.param(p["wq_b"]))              # [B,S,H*(dn+dr)]
+    if q_rank is None:
+        q = bk.matmul(x, bk.param(p["wq"]))             # [B,S,H*(dn+dr)]
+    else:
+        qc = bk.matmul(x, bk.param(p["wq_a"]))          # [B,S,q_rank]
+        qc = L.rmsnorm(bk, qc, p["q_norm"])
+        q = bk.matmul(qc, bk.param(p["wq_b"]))          # [B,S,H*(dn+dr)]
     q = bk.reshape(q, (B, S, H, d_nope + d_rope))
     q_nope = bk.slice(q, (Ellipsis, slice(0, d_nope)))
     q_rope = bk.slice(q, (Ellipsis, slice(d_nope, d_nope + d_rope)))
@@ -248,7 +255,8 @@ def mla_attention(
     q_lat = bk.einsum("bqhd,rhd->bqhr", q_nope, w_uk)   # [B,S,H,kv_rank]
     s_nope = bk.einsum("bqhr,bsr->bhqs", q_lat, c)
     s_rope = bk.einsum("bqhd,bsd->bhqs", q_rope, k_rope)
-    scale = (d_nope + d_rope) ** -0.5
+    scale = (softmax_scale if softmax_scale is not None
+             else (d_nope + d_rope) ** -0.5)
     scores = bk.scale(bk.add(s_nope, s_rope), scale)
     neg = bk.const(L.NEG_BIG)
     mb = mask[:, None, :, :] if mask.ndim == 3 else mask[None, None, :, :]
@@ -266,15 +274,19 @@ def mla_attention(
     return out, new_cache
 
 
-def init_mla(key, d: int, n_heads: int, q_rank: int, kv_rank: int,
+def init_mla(key, d: int, n_heads: int, q_rank: Optional[int], kv_rank: int,
              d_nope: int, d_rope: int, d_v: int):
     ks = jax.random.split(key, 5)
-    return {
-        "wq_a": L.dense_init(ks[0], d, q_rank),
-        "wq_b": L.dense_init(ks[1], q_rank, n_heads * (d_nope + d_rope)),
+    p = {
         "wkv_a": L.dense_init(ks[2], d, kv_rank + d_rope),
         "wkv_b": L.dense_init(ks[3], kv_rank, n_heads * (d_nope + d_v)),
         "wo": L.dense_init(ks[4], n_heads * d_v, d),
-        "q_norm": jnp.ones((q_rank,), jnp.float32),
         "kv_norm": jnp.ones((kv_rank,), jnp.float32),
     }
+    if q_rank is None:
+        p["wq"] = L.dense_init(ks[0], d, n_heads * (d_nope + d_rope))
+    else:
+        p.update(wq_a=L.dense_init(ks[0], d, q_rank),
+                 wq_b=L.dense_init(ks[1], q_rank, n_heads * (d_nope + d_rope)),
+                 q_norm=jnp.ones((q_rank,), jnp.float32))
+    return p

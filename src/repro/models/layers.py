@@ -109,14 +109,56 @@ def mlp_plain(bk, x, w_in, b_in, w_out, b_out, act: str = "gelu"):
 # rotary position embedding
 # --------------------------------------------------------------------------
 
-def rope_tables(positions, d_head: int, theta: float = 10000.0):
-    """cos/sin tables for the given positions: [S, d_head//2] each."""
+def rope_tables(positions, d_head: int, theta: float = 10000.0,
+                yarn: Optional[tuple] = None):
+    """cos/sin tables for the given positions: [S, d_head//2] each.
+    ``yarn`` = (factor, original_max_positions, beta_fast, beta_slow,
+    mscale, mscale_all_dim) gives YaRN's frequencies and table scale
+    (:func:`yarn_inv_freq`)."""
     half = d_head // 2
-    freqs = 1.0 / (
-        theta ** (jnp.arange(0, half, dtype=jnp.float32) / half)
-    )
+    scale = 1.0
+    if yarn is None:
+        freqs = 1.0 / (
+            theta ** (jnp.arange(0, half, dtype=jnp.float32) / half)
+        )
+    else:
+        freqs = yarn_inv_freq(d_head, theta, *yarn[:4])
+        factor, _, _, _, mscale, mscale_all_dim = yarn
+        scale = yarn_mscale(factor, mscale) / yarn_mscale(factor,
+                                                          mscale_all_dim)
     ang = positions.astype(jnp.float32)[:, None] * freqs[None, :]
-    return jnp.cos(ang), jnp.sin(ang)
+    if scale == 1.0:
+        return jnp.cos(ang), jnp.sin(ang)
+    return jnp.cos(ang) * scale, jnp.sin(ang) * scale
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature factor 0.1·mscale·ln(factor) + 1."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, factor: float, original: int,
+                  beta_fast: float, beta_slow: float):
+    """YaRN rotary frequencies (DeepseekV2YarnRotaryEmbedding): the
+    extrapolated base frequencies below the correction range
+    [low, high], those interpolated by ``factor`` above it, and a linear
+    ramp across it."""
+    def corr_dim(rot):
+        return (dim * math.log(original / (rot * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(corr_dim(beta_fast)), 0)
+    high = min(math.ceil(corr_dim(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    half = dim // 2
+    extra = 1.0 / theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    inter = 1.0 / (factor * theta ** (
+        jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp
+    return inter * (1.0 - keep) + extra * keep
 
 
 def apply_rope(bk, x, cos, sin):

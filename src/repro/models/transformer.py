@@ -1,5 +1,5 @@
-"""LM backbone assembly: one config-driven forward covering all 10 assigned
-architectures (dense / GQA / MLA / MoE / SWA / local-global+softcap / RWKV6 /
+"""LM backbone assembly: one config-driven forward covering every registered
+architecture (dense / GQA / MLA / MoE / SWA / local-global+softcap / RWKV6 /
 hybrid attn+mamba), backend-generic (JOps for train/serve, CaaOps for the
 paper's rigorous error analysis).
 
@@ -43,13 +43,20 @@ class ArchConfig:
     rope_theta: float = 10000.0
     tie_embeddings: bool = True
     embed_scale: bool = False                  # gemma-style sqrt(d) scaling
+    # YaRN rotary scaling (factor, original_max_positions, beta_fast,
+    # beta_slow, mscale, mscale_all_dim); None: plain RoPE
+    yarn: Optional[Tuple[float, int, float, float, float, float]] = None
     # MoE
     n_experts: Optional[int] = None
     top_k: Optional[int] = None
     moe_d_ff: Optional[int] = None
-    # MLA
+    first_k_dense: int = 0          # leading dense layers (width d_ff)
+    n_shared_experts: int = 0       # one gated MLP of n_shared·moe_d_ff
+    norm_topk_prob: bool = True     # renormalise the k chosen gates
+    routed_scaling: float = 1.0
+    # MLA (q_rank None: queries straight from wq, no compression)
     mla: bool = False
-    q_rank: int = 768
+    q_rank: Optional[int] = 768
     kv_rank: int = 256
     d_nope: int = 64
     d_rope: int = 32
@@ -91,7 +98,7 @@ class ArchConfig:
 # init
 # --------------------------------------------------------------------------
 
-def _init_layer(key, cfg: ArchConfig) -> Dict[str, Any]:
+def _init_layer(key, cfg: ArchConfig, dense: bool = False) -> Dict[str, Any]:
     ks = jax.random.split(key, 8)
     d, dh = cfg.d_model, cfg.head_dim
     p: Dict[str, Any] = {
@@ -113,8 +120,9 @@ def _init_layer(key, cfg: ArchConfig) -> Dict[str, Any]:
                                cfg.qkv_bias)
     if cfg.hybrid:
         p["mamba"] = S.init_mamba(ks[1], d, cfg.mamba_expand * d, cfg.ssm_state)
-    if cfg.family == "moe":
-        p["moe"] = M.init_moe(ks[2], d, cfg.moe_d_ff or cfg.d_ff, cfg.n_experts)
+    if cfg.family == "moe" and not dense:
+        p["moe"] = M.init_moe(ks[2], d, cfg.moe_d_ff or cfg.d_ff,
+                              cfg.n_experts, cfg.n_shared_experts)
     else:
         p["mlp"] = {
             "w_gate": L.dense_init(ks[3], d, cfg.d_ff),
@@ -127,11 +135,15 @@ def _init_layer(key, cfg: ArchConfig) -> Dict[str, Any]:
 def init_params(key, cfg: ArchConfig) -> Dict[str, Any]:
     k_emb, k_layers, k_head, k_enc, k_fr = jax.random.split(key, 5)
     layer_keys = jax.random.split(k_layers, cfg.n_layers)
+    nd = cfg.first_k_dense
     params: Dict[str, Any] = {
         "embed": L.embed_init(k_emb, cfg.vocab, cfg.d_model),
-        "layers": jax.vmap(lambda k: _init_layer(k, cfg))(layer_keys),
+        "layers": jax.vmap(lambda k: _init_layer(k, cfg))(layer_keys[nd:]),
         "final_norm": jnp.ones((cfg.d_model,), jnp.float32),
     }
+    if nd:
+        params["dense_layers"] = jax.vmap(
+            lambda k: _init_layer(k, cfg, dense=True))(layer_keys[:nd])
     if not cfg.tie_embeddings:
         params["head"] = L.embed_init(k_head, cfg.vocab, cfg.d_model)
     if cfg.enc_dec:
@@ -161,12 +173,25 @@ def _norm(bk, x, p, cfg, which: str):
     return L.rmsnorm(bk, x, p[which])
 
 
-def _mlp_or_moe(bk, x, p, cfg: ArchConfig):
-    if cfg.family == "moe":
-        return M.moe_mlp(bk, x, p["moe"], n_experts=cfg.n_experts,
-                         top_k=cfg.top_k, act=cfg.act)
+def _mlp_or_moe(bk, x, p, cfg: ArchConfig, experts=None, layer=0):
+    """The layer's MLP: its routed experts are ``experts``' layer
+    ``layer`` (the stack held whole) or ``p["moe"]``'s own."""
+    if "moe" in p:
+        return M.moe_mlp(bk, x, p["moe"], experts, layer,
+                         n_experts=cfg.n_experts, top_k=cfg.top_k,
+                         act=cfg.act, norm_topk_prob=cfg.norm_topk_prob,
+                         routed_scaling=cfg.routed_scaling)
     return L.mlp_gated(bk, x, p["mlp"]["w_gate"], p["mlp"]["w_up"],
                        p["mlp"]["w_down"], cfg.act)
+
+
+def softmax_scale(cfg: ArchConfig) -> float:
+    """Attention score scale: 1/√(query width), times YaRN's
+    mscale(mscale_all_dim)² where the rotary embedding is YaRN's
+    (DeepseekV2Attention)."""
+    width = cfg.d_nope + cfg.d_rope if cfg.mla else cfg.head_dim
+    m = 1.0 if cfg.yarn is None else L.yarn_mscale(cfg.yarn[0], cfg.yarn[5])
+    return width ** -0.5 * m * m
 
 
 def _layer_masks(cfg: ArchConfig, q_len: int, kv_len: int, q_offset=0):
@@ -228,7 +253,7 @@ def forward(
         positions = jnp.arange(Sq) + q_offset
     rope_positions = jnp.arange(kv_len) if cache is not None else positions
     cos_full, sin_full = L.rope_tables(rope_positions, _rope_dim(cfg),
-                                       cfg.rope_theta)
+                                       cfg.rope_theta, cfg.yarn)
     if ragged:
         cos_q = jnp.take(cos_full, positions, axis=0)   # [B, Sq, half]
         sin_q = jnp.take(sin_full, positions, axis=0)
@@ -252,16 +277,31 @@ def forward(
         # for every decoded token was a 3300x HLO-flop bug (§Perf)
         enc_out = encode(bk, params, cfg, enc_embeds)
 
+    # routed experts stay whole, out of the scanned layer slices: each
+    # layer's products read their [E, K, N] blocks in place, by index
+    lp = dict(params["layers"])
+    experts = None
+    if "moe" in lp:
+        moe = dict(lp["moe"])
+        experts = {k: moe.pop(k) for k in M.EXPERT_KEYS}
+        lp["moe"] = moe
+    nd = cfg.first_k_dense
+
     def layer_fn(p, x, i, aux):
         x, aux_out = _one_layer(bk, p, x, i, aux, cfg, cos_q, sin_q,
                                 gmask, lmask, enc_out, q_offset,
-                                fused_ok=fused_ok)
+                                fused_ok=fused_ok, experts=experts,
+                                expert_layer=i - nd)
         return x, aux_out
 
-    lp = dict(params["layers"])
     if cfg.enc_dec:
         lp["cross"] = params["cross"]
-    x, new_cache = bk.layer_loop(layer_fn, lp, x, cfg.n_layers, aux=cache)
+    new_cache = cache
+    if nd:
+        x, new_cache = bk.layer_loop(layer_fn, params["dense_layers"], x,
+                                     nd, aux=new_cache)
+    x, new_cache = bk.layer_loop(layer_fn, lp, x, cfg.n_layers - nd,
+                                 aux=new_cache, first=nd)
 
     with bk.scope("head"):
         x = L.rmsnorm(bk, x, params["final_norm"])
@@ -288,7 +328,8 @@ def _cache_len(cache) -> int:
 
 
 def _one_layer(bk, p, x, i, aux, cfg, cos, sin, gmask, lmask, enc_out,
-               q_offset, fused_ok: bool = False):
+               q_offset, fused_ok: bool = False, experts=None,
+               expert_layer=0):
     h = _norm(bk, x, p, cfg, "ln1")
     aux_out = None
 
@@ -332,7 +373,7 @@ def _one_layer(bk, p, x, i, aux, cfg, cos, sin, gmask, lmask, enc_out,
                 bk, h, p["attn"], n_heads=cfg.n_heads, q_rank=cfg.q_rank,
                 kv_rank=cfg.kv_rank, d_nope=cfg.d_nope, d_rope=cfg.d_rope,
                 d_v=cfg.d_v, cos=cos, sin=sin, mask=mask, cache=kv_cache,
-                q_offset=q_offset)
+                q_offset=q_offset, softmax_scale=softmax_scale(cfg))
         else:
             out, new_kv = A.gqa_attention(
                 bk, h, p["attn"], n_heads=cfg.n_heads,
@@ -359,7 +400,7 @@ def _one_layer(bk, p, x, i, aux, cfg, cos, sin, gmask, lmask, enc_out,
 
     h2 = _norm(bk, x, p, cfg, "ln2")
     with bk.scope("mlp"):
-        mlp_out = _mlp_or_moe(bk, h2, p, cfg)
+        mlp_out = _mlp_or_moe(bk, h2, p, cfg, experts, expert_layer)
     x = bk.add(x, mlp_out)
 
     if new_kv is not None:
@@ -426,13 +467,15 @@ def analytic_params(cfg: ArchConfig, active: bool = False) -> int:
     roofline model and the per-arch auto policies (§Perf policy matrix)."""
     d, dh = cfg.d_model, cfg.head_dim
     emb = cfg.vocab * d * (1 if cfg.tie_embeddings else 2)
-    per_layer = 0
+    per_layer = dense_swap = 0
     if cfg.rwkv:
         per_layer += 5 * d * d + d * 64 + 64 * d
         per_layer += d * cfg.d_ff + cfg.d_ff * d + d * d
     else:
         if cfg.mla:
-            per_layer += d * cfg.q_rank + cfg.q_rank * cfg.n_heads * (cfg.d_nope + cfg.d_rope)
+            q_in = d if cfg.q_rank is None else cfg.q_rank
+            per_layer += (0 if cfg.q_rank is None else d * cfg.q_rank)
+            per_layer += q_in * cfg.n_heads * (cfg.d_nope + cfg.d_rope)
             per_layer += d * (cfg.kv_rank + cfg.d_rope)
             per_layer += cfg.kv_rank * cfg.n_heads * (cfg.d_nope + cfg.d_v)
             per_layer += cfg.n_heads * cfg.d_v * d
@@ -445,11 +488,14 @@ def analytic_params(cfg: ArchConfig, active: bool = False) -> int:
         if cfg.family == "moe":
             e = cfg.n_experts if not active else cfg.top_k
             ff = cfg.moe_d_ff or cfg.d_ff
-            per_layer += d * cfg.n_experts
-            per_layer += e * (2 * d * ff + ff * d)
+            moe_mlp = (d * cfg.n_experts
+                       + (e + cfg.n_shared_experts) * 3 * d * ff)
+            # leading dense layers: a dense MLP in place of the experts
+            dense_swap = cfg.first_k_dense * (3 * d * cfg.d_ff - moe_mlp)
+            per_layer += moe_mlp
         else:
             per_layer += 3 * d * cfg.d_ff
-    n = emb + cfg.n_layers * per_layer
+    n = emb + cfg.n_layers * per_layer + dense_swap
     if cfg.enc_dec:
         n += cfg.n_enc_layers * (4 * d * dh * cfg.n_heads + 3 * d * cfg.d_ff)
         n += cfg.n_layers * 4 * d * dh * cfg.n_heads

@@ -26,6 +26,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 # -- helpers ---------------------------------------------------------------
 
+# parameter subtrees stacked [L, ...] along a leading layer axis
+STACKS = ("layers", "dense_layers", "enc_layers", "cross")
+
 def _axis_size(mesh: Mesh, name: str) -> int:
     return dict(zip(mesh.axis_names, mesh.devices.shape)).get(name, 1)
 
@@ -71,7 +74,7 @@ def shard_params(params, mesh: Mesh, *, model_only: bool = False) -> Any:
     def one(path, leaf):
         shape = leaf.shape if hasattr(leaf, "shape") else ()
         keys = [getattr(k, "key", str(k)) for k in path]
-        stacked = any(k in ("layers", "enc_layers", "cross") for k in keys)
+        stacked = any(k in STACKS for k in keys)
         # expert-parallel weights: shard the expert dim over "model" (the
         # shard_map MoE path requires it); [L, E, d, ff] → P(None,"model",..)
         if "moe" in keys and keys[-1] in ("w_gate", "w_up", "w_down"):
@@ -206,7 +209,7 @@ def serving_param_spec(path_keys, shape, mesh: Mesh, *,
     nbytes = int(np.prod(shape)) * 4 if shape else 0
     if m_sz <= 1 or len(shape) < 2 or nbytes < min_shard_bytes:
         return P(*spec)
-    stacked = any(k in ("layers", "enc_layers", "cross") for k in path_keys)
+    stacked = any(k in STACKS for k in path_keys)
     if path_keys and path_keys[-1] in ("embed", "head"):
         dim = 1 if stacked else 0
     else:

@@ -118,13 +118,15 @@ class _UnrolledJOps(UnrolledLayerLoop, JOps):
 
 
 @pytest.mark.parametrize("arch", ["qwen2_7b", "minicpm3_4b", "rwkv6_1p6b",
-                                  "hymba_1p5b"])
+                                  "hymba_1p5b", "deepseek_v2_lite"])
 def test_scanned_cache_carry_matches_unrolled(arch):
     """The scanned layer loop threads the stacked cache through its carry;
     the unrolled reference runs the same layer function with a static
     layer index. A chunk then a decode step at batch 3, each lane at its
     own position, give bitwise-equal logits and new caches, for each kind
-    of cache: GQA K/V, MLA latent, RWKV state, hybrid K/V + SSM state."""
+    of cache: GQA K/V, MLA latent, RWKV state, hybrid K/V + SSM state, and
+    one latent cache written by a leading dense stack and the MoE stack
+    after it (DeepSeek)."""
     cfg = configs.get(arch).SMOKE
     params = T.init_params(jax.random.PRNGKey(6), cfg)
     rng = np.random.RandomState(6)
@@ -203,6 +205,14 @@ def test_full_configs_match_assignment():
     c = configs.get("whisper_medium").FULL
     assert c.enc_dec and (c.n_layers, c.n_enc_layers, c.d_model,
                           c.d_ff) == (24, 24, 1024, 4096)
+    c = configs.get("deepseek_v2_lite").FULL
+    assert c.mla and c.q_rank is None and (
+        c.n_layers, c.d_model, c.n_heads, c.kv_rank, c.d_nope, c.d_rope,
+        c.d_v) == (27, 2048, 16, 512, 128, 64, 128)
+    assert (c.d_ff, c.vocab, c.n_experts, c.top_k, c.moe_d_ff,
+            c.first_k_dense, c.n_shared_experts) == (10944, 102400, 64, 6,
+                                                     1408, 1, 2)
+    assert c.yarn == (40.0, 4096, 32.0, 1.0, 0.707, 0.707)
     c = configs.get("paligemma_3b").FULL
     assert (c.n_layers, c.d_model, c.n_heads, c.n_kv_heads, c.d_ff,
             c.vocab) == (18, 2048, 8, 1, 16384, 257216)

@@ -249,18 +249,22 @@ def test_grad_compression_error_feedback():
     assert float(jnp.abs(ef.residual["x"]).max()) < 1.0
 
 
-def test_moe_dense_vs_dropping_equivalence():
-    """With generous capacity, the dropping path must match dense combine."""
+@pytest.mark.parametrize("renorm, n_shared", [(True, 0), (False, 2)],
+                         ids=["mixtral", "deepseek"])
+def test_moe_dense_vs_dropless_equivalence(renorm, n_shared):
+    """The dropless routed experts (the serving path) compute the dense
+    dispatch's function, the mode the CAA analysis runs: every expert on
+    every token, gated by the top-k mask, nothing dropped."""
     from repro.models import moe as M
     key = jax.random.PRNGKey(0)
-    p = M.init_moe(key, d=16, d_ff=32, n_experts=4)
-    x = jax.random.normal(jax.random.PRNGKey(1), (1, 12, 16))
+    p = M.init_moe(key, d=16, d_ff=32, n_experts=8, n_shared=n_shared)
+    x = jax.random.normal(jax.random.PRNGKey(1), (1, 6, 16))
+    kw = dict(n_experts=8, top_k=3, norm_topk_prob=renorm)
     bk = JOps()
-    y_dense = M.moe_mlp(bk, x, p, n_experts=4, top_k=2, mode="dense")
-    y_drop = M.moe_mlp(bk, x, p, n_experts=4, top_k=2, mode="dropping",
-                       capacity_factor=4.0, chunk_tokens=12)
-    np.testing.assert_allclose(np.asarray(y_dense), np.asarray(y_drop),
-                               rtol=2e-4, atol=2e-5)
+    y_dense = M.moe_mlp(bk, x, p, mode="dense", **kw)
+    y_drop = M.moe_mlp(bk, x, p, mode="dropless", **kw)
+    np.testing.assert_allclose(np.asarray(y_drop), np.asarray(y_dense),
+                               rtol=1e-5, atol=1e-6)
 
 
 def test_rwkv_chunked_matches_stepwise():
